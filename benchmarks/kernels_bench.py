@@ -53,5 +53,7 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    from repro import runtime
+    runtime.enable_compile_cache()
     for r in run():
         print(r)

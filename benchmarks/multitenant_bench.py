@@ -93,6 +93,7 @@ import numpy as np
 sys.path.insert(0, ".")
 from benchmarks.common import row, timed  # noqa: E402
 
+from repro import runtime  # noqa: E402
 from repro.configs.base import ModelConfig  # noqa: E402
 from repro.core.lora import init_adapters  # noqa: E402
 from repro.kernels.batched_lora import batched_lora_matmul  # noqa: E402
@@ -1284,6 +1285,7 @@ def main(argv=None):
     ap.add_argument("--json", default="BENCH_serving.json",
                     help="where the ragged-workload record is written")
     args = ap.parse_args(argv)
+    runtime.enable_compile_cache()
 
     print("name,us_per_call,derived")
     if args.block_sweep:

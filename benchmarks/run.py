@@ -27,6 +27,9 @@ MODULES = [
 
 def main() -> None:
     import importlib
+
+    from repro import runtime
+    runtime.enable_compile_cache()
     want = set(sys.argv[1:])
     print("name,us_per_call,derived")
     failed = []

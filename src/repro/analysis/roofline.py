@@ -17,7 +17,8 @@ op's *output* shape (g = collective group size):
     all-to-all       S·(g-1)/g
     collective-permute  S
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Peak rates come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``;
+a kind missing from the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -25,9 +26,27 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float                # bf16 FLOP/s per chip
+    hbm_bw: float               # HBM bytes/s per chip
+    ici_bw: float               # interconnect bytes/s per link
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over four links
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peak rates of one chip of ``device_kind``."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -117,11 +136,13 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def analyze(cost: Dict, hlo_text: str, chips: int,
-            model_flops: float = 0.0) -> Roofline:
+def analyze(cost: Dict, hlo_text: str, chips: int, model_flops: float = 0.0,
+            *, device_kind: str) -> Roofline:
     """``cost`` comes from ``compiled.cost_analysis()``, which reports the
     SPMD-partitioned (per-device) module — flops/bytes are PER CHIP (verified
-    against a hand-computed matmul; tests/test_roofline.py)."""
+    against a hand-computed matmul; tests/test_roofline.py).  Times are
+    against the peaks of one ``device_kind`` chip."""
+    peak = peaks(device_kind)
     flops = float(cost.get("flops", 0.0))          # per chip
     hbm = float(cost.get("bytes accessed", 0.0))   # per chip
     colls = parse_collectives(hlo_text)
@@ -130,9 +151,9 @@ def analyze(cost: Dict, hlo_text: str, chips: int,
     for c in colls:
         by_op[c.op] = by_op.get(c.op, 0.0) + c.per_chip_bytes
 
-    compute_s = flops / PEAK_FLOPS
-    memory_s = hbm / HBM_BW
-    coll_s = per_chip_coll / ICI_BW
+    compute_s = flops / peak.flops
+    memory_s = hbm / peak.hbm_bw
+    coll_s = per_chip_coll / peak.ici_bw
     dominant = max(
         (("compute", compute_s), ("memory", memory_s), ("collective", coll_s)),
         key=lambda kv: kv[1])[0]

@@ -49,12 +49,13 @@ class ModelConfig:
     attn_impl: str = "repeat"        # repeat | grouped (no KV materialization)
     attn_softmax_dtype: str = "float32"  # float32 | bfloat16 logits/probs
     # serving paged-attention backend: "jnp" materialises the block-table
-    # gather (CPU oracle, bitwise-stable default); "pallas" routes the paged
-    # branch of layers.multihead_attention through kernels/paged_attention.py
-    # + kernels/paged_prefill.py (ServeConfig.paged_backend threads this
-    # per-stream; full attention only — no sliding window / logit softcap)
-    paged_backend: str = "jnp"       # jnp | pallas
-    pallas_interpret: bool = True    # False on TPU: compile the kernels
+    # gather (the reference); "pallas" routes the paged branch of
+    # layers.multihead_attention through kernels/paged_attention.py +
+    # kernels/paged_prefill.py (full attention only — no sliding window /
+    # logit softcap).  None = the platform's choice (repro.runtime:
+    # pallas on TPU, jnp on CPU); ServeConfig.paged_backend overrides it
+    # per stream.
+    paged_backend: Optional[str] = None   # None | jnp | pallas
 
     # Norm / activation flavour
     norm_type: str = "rmsnorm"       # rmsnorm | layernorm | nonparametric
@@ -113,7 +114,7 @@ class ModelConfig:
     citation: str = ""
 
     def __post_init__(self):
-        assert self.paged_backend in ("jnp", "pallas"), (
+        assert self.paged_backend in (None, "jnp", "pallas"), (
             f"{self.name}: unknown paged_backend {self.paged_backend!r}")
         for p in self.layer_pattern:
             mixer, _, mlp = p.partition("+")

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
+
 
 def _kernel(x_ref, w_ref, oh_ref, *rest,
             scale: float, k_steps: int, n_clients: int, quantized: bool,
@@ -148,12 +150,10 @@ def _lane_pad(x, mult: int = 128):
     return x
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "bk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "bk"))
 def batched_lora_matmul(x, w, a, b, adapter_ids, scale: float = 1.0, *,
                         a_scale=None, b_scale=None, ranks=None,
-                        bm: int = 256, bn: int = 256, bk: int = 256,
-                        interpret: bool = True):
+                        bm: int = 256, bn: int = 256, bk: int = 256):
     """x: (M, K), w: (K, N), a: (C, K, r), b: (C, r, N),
     adapter_ids: (M,) int32 in [0, C) -> (M, N).
 
@@ -169,7 +169,6 @@ def batched_lora_matmul(x, w, a, b, adapter_ids, scale: float = 1.0, *,
     exact zeros regardless of what lives in them.
 
     M, K, N must tile by (bm, bn, bk); r is zero-padded to 128 internally.
-    ``interpret=True`` executes on CPU for validation; on TPU pass False.
     """
     M, K = x.shape
     N = w.shape[1]
@@ -215,16 +214,14 @@ def batched_lora_matmul(x, w, a, b, adapter_ids, scale: float = 1.0, *,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r_pad), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=runtime.interpret_mode(),
     )(*operands)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "bk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "bk"))
 def batched_dual_lora_matmul(x, w, a1, b1, a2, b2, adapter_ids, fusion_w,
                              scale: float = 1.0, *,
-                             bm: int = 256, bn: int = 256, bk: int = 256,
-                             interpret: bool = True):
+                             bm: int = 256, bn: int = 256, bk: int = 256):
     """Per-request Eq. 7: x: (M, K), w: (K, N), a1/b1: (C, K, r)/(C, r, N)
     personalized bank, a2/b2: (K, r)/(r, N) shared global θ_s,
     adapter_ids: (M,) int32, fusion_w: (M, 2) fp32 per-row [w1, w2]."""
@@ -265,5 +262,5 @@ def batched_dual_lora_matmul(x, w, a1, b1, a2, b2, adapter_ids, fusion_w,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r_pad), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=runtime.interpret_mode(),
     )(x, w, oh, fusion_w, a1p, b1p, a2, b2)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
+
 
 def _kernel(x_ref, w_ref, a1_ref, b1_ref, a2_ref, b2_ref, fw_ref,
             o_ref, acc_ref, zacc_ref, *, scale: float, k_steps: int):
@@ -46,11 +48,9 @@ def _kernel(x_ref, w_ref, a1_ref, b1_ref, a2_ref, b2_ref, fw_ref,
         o_ref[...] = (acc_ref[...] + scale * lora).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "bk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "bk"))
 def dual_lora_matmul(x, w, a1, b1, a2, b2, fusion_w, scale: float = 1.0, *,
-                     bm: int = 256, bn: int = 256, bk: int = 256,
-                     interpret: bool = True):
+                     bm: int = 256, bn: int = 256, bk: int = 256):
     """x: (M,K), w: (K,N), a1/a2: (K,r), b1/b2: (r,N), fusion_w: (2,) fp32."""
     M, K = x.shape
     N = w.shape[1]
@@ -82,5 +82,5 @@ def dual_lora_matmul(x, w, a1, b1, a2, b2, fusion_w, scale: float = 1.0, *,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r_pad), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=runtime.interpret_mode(),
     )(x, w, a1, b1, a2, b2, fusion_w)

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
+
 NEG_INF = -1e30
 
 
@@ -77,10 +79,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sliding_window",
-                                             "scale", "bq", "bk", "interpret"))
+                                             "scale", "bq", "bk"))
 def flash_attention(q, k, v, *, causal: bool = True, sliding_window: int = 0,
-                    scale: float | None = None, bq: int = 128, bk: int = 128,
-                    interpret: bool = True):
+                    scale: float | None = None, bq: int = 128, bk: int = 128):
     """q: (B, H, Sq, d), k/v: (B, H, Sk, d) -> (B, H, Sq, d)."""
     B, H, Sq, d = q.shape
     Sk = k.shape[2]
@@ -111,6 +112,6 @@ def flash_attention(q, k, v, *, causal: bool = True, sliding_window: int = 0,
             pltpu.VMEM((bq, 128), jnp.float32),   # running denom
             pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
         ],
-        interpret=interpret,
+        interpret=runtime.interpret_mode(),
     )(qf, kf, vf)
     return out.reshape(B, H, Sq, d)

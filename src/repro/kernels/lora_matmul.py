@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
+
 
 def _kernel(x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, zacc_ref, *,
             scale: float, k_steps: int):
@@ -47,14 +49,12 @@ def _kernel(x_ref, w_ref, a_ref, b_ref, o_ref, acc_ref, zacc_ref, *,
         o_ref[...] = (acc_ref[...] + scale * lora).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "bk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "bm", "bn", "bk"))
 def lora_matmul(x, w, a, b, scale: float = 1.0, *, bm: int = 256,
-                bn: int = 256, bk: int = 256, interpret: bool = True):
+                bn: int = 256, bk: int = 256):
     """x: (M, K), w: (K, N), a: (K, r), b: (r, N) -> (M, N).
 
     M, K, N must tile by (bm, bk, bn); r is zero-padded to 128 internally.
-    ``interpret=True`` executes on CPU for validation; on TPU pass False.
     """
     M, K = x.shape
     N = w.shape[1]
@@ -83,5 +83,5 @@ def lora_matmul(x, w, a, b, scale: float = 1.0, *, bm: int = 256,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r_pad), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=runtime.interpret_mode(),
     )(x, w, a, b)

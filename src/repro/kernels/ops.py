@@ -1,8 +1,8 @@
 """jit'd high-level wrappers over the Pallas kernels.
 
 These adapt model-layer calling conventions ((B, S, d) activations, GQA
-head layouts, adapter dicts) to the 2-D kernel interfaces. On CPU they run
-in ``interpret=True`` (validation); on TPU pass ``interpret=False``.
+head layouts, adapter dicts) to the 2-D kernel interfaces. The kernels run
+compiled on TPU and interpreted on CPU (``repro.runtime.interpret_mode``).
 
 The model layer keeps pure-jnp math by default (``layers.dense`` /
 ``multihead_attention``) — the kernels are drop-in replacements for the
@@ -14,6 +14,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.batched_lora import batched_lora_matmul
 from repro.kernels.dual_lora import dual_lora_matmul
@@ -34,9 +35,24 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths), size
 
 
+def _per_data_shard(kernel, rows, shared):
+    """``kernel(*rows, *shared)``, run once per "data" shard of the batch
+    when the mesh in scope (``jax.set_mesh``) splits it: XLA cannot
+    partition a Mosaic kernel, so under a multi-device mesh each device
+    calls it on its own rows (``rows``: leading batch axis, split on
+    "data") against the whole of ``shared`` (the block pools, replicated).
+    ``shared`` entries may be None.  Without such a mesh: a plain call."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.shape.get("data", 1) == 1:
+        return kernel(*rows, *shared)
+    return jax.shard_map(
+        lambda rows, shared: kernel(*rows, *shared),
+        in_specs=(P("data"), P()), out_specs=P("data"),
+        check_vma=False)(tuple(rows), tuple(shared))
+
+
 def lora_dense(x: jnp.ndarray, w: jnp.ndarray, adapter: Dict[str, jnp.ndarray],
-               scale: float, *, interpret: bool = True,
-               block: int = 256) -> jnp.ndarray:
+               scale: float, *, block: int = 256) -> jnp.ndarray:
     """(..., K) @ (K, N) + LoRA via the fused kernel. Pads M/K/N to tiles."""
     lead = x.shape[:-1]
     K = x.shape[-1]
@@ -48,14 +64,13 @@ def lora_dense(x: jnp.ndarray, w: jnp.ndarray, adapter: Dict[str, jnp.ndarray],
     ap, _ = _pad_to(adapter["a"], 0, block)
     bp, _ = _pad_to(adapter["b"], 1, block)
     y = lora_matmul(x2p.astype(jnp.bfloat16), wp, ap, bp, scale,
-                    bm=block, bn=block, bk=block, interpret=interpret)
+                    bm=block, bn=block, bk=block)
     return y[:M, :N].reshape(*lead, N)
 
 
 def fused_dual_lora_dense(x: jnp.ndarray, w: jnp.ndarray,
                           ad_p: Dict, ad_s: Dict, fusion_w: jnp.ndarray,
-                          scale: float, *, interpret: bool = True,
-                          block: int = 256) -> jnp.ndarray:
+                          scale: float, *, block: int = 256) -> jnp.ndarray:
     """FDLoRA serving path: base + Eq.7-merged dual adapters, one kernel."""
     lead = x.shape[:-1]
     K = x.shape[-1]
@@ -69,15 +84,13 @@ def fused_dual_lora_dense(x: jnp.ndarray, w: jnp.ndarray,
     a2, _ = _pad_to(ad_s["a"], 0, block)
     b2, _ = _pad_to(ad_s["b"], 1, block)
     y = dual_lora_matmul(x2p.astype(jnp.bfloat16), wp, a1, b1, a2, b2,
-                         fusion_w, scale, bm=block, bn=block, bk=block,
-                         interpret=interpret)
+                         fusion_w, scale, bm=block, bn=block, bk=block)
     return y[:M, :N].reshape(*lead, N)
 
 
 def batched_lora_dense(x: jnp.ndarray, w: jnp.ndarray,
                        bank: Dict[str, jnp.ndarray], adapter_ids: jnp.ndarray,
-                       scale: float, *, interpret: bool = True,
-                       block: int = 256) -> jnp.ndarray:
+                       scale: float, *, block: int = 256) -> jnp.ndarray:
     """Multi-tenant dense: (B, ..., K) @ (K, N) with per-*request* adapter
     routing. ``bank`` = {"a": (C, K, r), "b": (C, r, N)}; an int8 bank also
     carries ``a_scale``/``b_scale`` ((C,) fp32) which the kernel applies as
@@ -129,7 +142,7 @@ def batched_lora_dense(x: jnp.ndarray, w: jnp.ndarray,
     y = batched_lora_matmul(x2p.astype(jnp.bfloat16), wp, ap, bp, g, scale,
                             a_scale=bank.get("a_scale"),
                             b_scale=bank.get("b_scale"), ranks=ranks,
-                            bm=block, bn=block, bk=block, interpret=interpret)
+                            bm=block, bn=block, bk=block)
     return y[:M, :N].reshape(*lead, N)
 
 
@@ -137,8 +150,8 @@ def paged_gqa_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                         v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                         lengths: jnp.ndarray, *,
                         k_scale: Optional[jnp.ndarray] = None,
-                        v_scale: Optional[jnp.ndarray] = None,
-                        interpret: bool = True) -> jnp.ndarray:
+                        v_scale: Optional[jnp.ndarray] = None
+                        ) -> jnp.ndarray:
     """Model-layout adapter for the paged decode kernel.
 
     q: (B, 1, H, hd) (or (B, H, hd)) as produced by the serving decode step;
@@ -162,10 +175,11 @@ def paged_gqa_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     qp, _ = _pad_to(q, 2, 128)
     kp, _ = _pad_to(k_pool, 3, 128)
     vp, _ = _pad_to(v_pool, 3, 128)
-    o = paged_attention(qp, kp, vp, block_tables.astype(jnp.int32),
-                        lengths.astype(jnp.int32),
-                        k_scale=k_scale, v_scale=v_scale, scale=scale,
-                        interpret=interpret)[..., :hd]
+    o = _per_data_shard(
+        lambda q, bt, ln, kp, vp, ks, vs: paged_attention(
+            q, kp, vp, bt, ln, k_scale=ks, v_scale=vs, scale=scale),
+        (qp, block_tables.astype(jnp.int32), lengths.astype(jnp.int32)),
+        (kp, vp, k_scale, v_scale))[..., :hd]
     return o[:, None] if squeeze else o
 
 
@@ -176,8 +190,7 @@ def paged_prefill_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                                 lengths: jnp.ndarray,
                                 n_new: jnp.ndarray, *,
                                 k_scale: Optional[jnp.ndarray] = None,
-                                v_scale: Optional[jnp.ndarray] = None,
-                                interpret: bool = True):
+                                v_scale: Optional[jnp.ndarray] = None):
     """Model-layout adapter for the chunked paged-prefill kernel.
 
     q/k_new/v_new: (B, T, H|Kv, hd) — a whole prompt chunk per serving slot,
@@ -210,18 +223,19 @@ def paged_prefill_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
     qp, _ = _pad_to(q, 3, 128)
     kpp, _ = _pad_to(kp, 3, 128)
     vpp, _ = _pad_to(vp, 3, 128)
-    o = paged_prefill_attention(qp, kpp, vpp, block_tables.astype(jnp.int32),
-                                lengths.astype(jnp.int32),
-                                k_scale=ks, v_scale=vs, scale=scale,
-                                interpret=interpret)[..., :hd]
+    o = _per_data_shard(
+        lambda q, bt, ln, kp, vp, ks, vs: paged_prefill_attention(
+            q, kp, vp, bt, ln, k_scale=ks, v_scale=vs, scale=scale),
+        (qp, block_tables.astype(jnp.int32), lengths.astype(jnp.int32)),
+        (kpp, vpp, ks, vs))[..., :hd]
     if quantized:
         return o, kp, vp, ks, vs
     return o, kp, vp
 
 
 def gqa_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
-                        causal: bool = True, sliding_window: int = 0,
-                        interpret: bool = True) -> jnp.ndarray:
+                        causal: bool = True, sliding_window: int = 0
+                        ) -> jnp.ndarray:
     """GQA layout adapter: q (B, Sq, H, d), k/v (B, Sk, Kv, d) as produced by
     the model layer -> flash kernel layout, repeating KV heads."""
     B, Sq, H, d = q.shape
@@ -231,5 +245,5 @@ def gqa_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kt = jnp.repeat(k.transpose(0, 2, 1, 3), rep, axis=1)
     vt = jnp.repeat(v.transpose(0, 2, 1, 3), rep, axis=1)
     o = flash_attention(qt, kt, vt, causal=causal,
-                        sliding_window=sliding_window, interpret=interpret)
+                        sliding_window=sliding_window)
     return o.transpose(0, 2, 1, 3)

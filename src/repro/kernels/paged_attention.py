@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
+
 NEG_INF = -1e30
 
 
@@ -83,10 +85,9 @@ def _kernel(bt_ref, len_ref, q_ref, *rest, scale: float, bs: int, mb: int,
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale",))
 def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                    k_scale=None, v_scale=None,
-                    scale: float | None = None, interpret: bool = True):
+                    k_scale=None, v_scale=None, scale: float | None = None):
     """q: (B, H, hd) decode queries; k_pool/v_pool: (NB, bs, Kv, hd) shared
     block pools; block_tables: (B, MB) int32 physical block ids per row;
     lengths: (B,) int32 valid context per row.  Returns (B, H, hd).
@@ -105,7 +106,6 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
     decoded from its own attention.
 
     H must be a multiple of Kv (GQA groups fold into the query tile).
-    ``interpret=True`` executes on CPU for validation; on TPU pass False.
     """
     B, H, hd = q.shape
     NB, bs, Kv, _ = k_pool.shape
@@ -154,6 +154,6 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, hd), q.dtype),
-        interpret=interpret,
+        interpret=runtime.interpret_mode(),
     )(block_tables, lengths, *operands)
     return out.reshape(B, H, hd)

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import runtime
 from repro.kernels.quant import quantize_int8
 
 NEG_INF = -1e30
@@ -162,11 +163,10 @@ def _kernel(bt_ref, len_ref, q_ref, *rest, scale: float, bs: int, mb: int,
         o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale",))
 def paged_prefill_attention(q, k_pool, v_pool, block_tables, lengths, *,
                             k_scale=None, v_scale=None,
-                            scale: float | None = None,
-                            interpret: bool = True):
+                            scale: float | None = None):
     """q: (B, T, H, hd) — T chunk queries per row at absolute positions
     ``lengths[b] + t``; k_pool/v_pool: (NB, bs, Kv, hd) shared pools WITH
     the chunk's K/V already scattered in; block_tables: (B, MB) int32;
@@ -179,7 +179,7 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, lengths, *,
     written by ``paged_scatter_quant``): each grid step DMAs the block's
     scale tile alongside its values and dequantizes in VMEM — the fp32
     K/V gather still never materialises in HBM.
-    H must be a multiple of Kv.  ``interpret=True`` runs on CPU.
+    H must be a multiple of Kv.
     """
     B, T, H, hd = q.shape
     NB, bs, Kv, _ = k_pool.shape
@@ -228,7 +228,7 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, lengths, *,
                           quantized=quantized),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, T * G, hd), q.dtype),
-        interpret=interpret,
+        interpret=runtime.interpret_mode(),
     )(block_tables, lengths, *operands)
     return (out.reshape(B, Kv, T, G, hd)
                .transpose(0, 2, 1, 3, 4)

@@ -35,6 +35,9 @@ from repro.models.api import get_model
 from repro.training.optimizers import adamw, sgd
 from repro.training.train_step import make_lora_train_step
 
+# the chip the production mesh is made of; roofline terms use its peaks
+DEVICE_KIND = "TPU v5 lite"
+
 
 def _shardings(mesh, spec_tree, shape_tree):
     """NamedShardings; axes that don't divide a dim are dropped (replicated)."""
@@ -236,13 +239,6 @@ def _compile_once(cfg, mesh, shape_name, step):
     return compiled, model_flops
 
 
-def cost_dict(compiled):
-    """compiled.cost_analysis() as a dict: jaxlib <= 0.4.x wraps it in a
-    one-element list, newer versions return the dict directly."""
-    cost = compiled.cost_analysis()
-    return cost[0] if isinstance(cost, (list, tuple)) else cost
-
-
 def _extrapolated_cost(cfg, mesh, shape_name, step):
     """Exact flops/bytes/collectives via depth extrapolation.
 
@@ -256,7 +252,7 @@ def _extrapolated_cost(cfg, mesh, shape_name, step):
     if P == 1:
         c, _ = _compile_once(cfg.with_overrides(scan_unroll=1), mesh,
                              shape_name, step)
-        cost = cost_dict(c)
+        cost = c.cost_analysis()
         colls = rl.parse_collectives(c.as_text())
         return (float(cost.get("flops", 0)), float(cost.get("bytes accessed", 0)),
                 sum(x.per_chip_bytes for x in colls), colls)
@@ -267,7 +263,7 @@ def _extrapolated_cost(cfg, mesh, shape_name, step):
     out = []
     for p in (pa, pb):
         c, _ = _compile_once(_reduced_cfg(cfg, p), mesh, shape_name, step)
-        cost = cost_dict(c)
+        cost = c.cost_analysis()
         colls = rl.parse_collectives(c.as_text())
         out.append((float(cost.get("flops", 0)),
                     float(cost.get("bytes accessed", 0)),
@@ -335,9 +331,9 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, step: str = "auto",
 
     chips = mesh.devices.size
     roof = rl.analyze({"flops": flops, "bytes accessed": hbm}, "", chips,
-                      model_flops)
+                      model_flops, device_kind=DEVICE_KIND)
     roof.collective_bytes = coll_bytes
-    roof.collective_s = coll_bytes / rl.ICI_BW
+    roof.collective_s = coll_bytes / rl.peaks(DEVICE_KIND).ici_bw
     roof.n_collectives = len(colls)
     roof.coll_by_op = {}
     for c in colls:

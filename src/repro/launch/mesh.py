@@ -12,15 +12,6 @@ from __future__ import annotations
 import jax
 
 
-def _mesh_kwargs(n):
-    """``axis_types`` only exists on newer jax; 0.4.37 meshes are implicitly
-    Auto, so omit the kwarg there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
@@ -32,9 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"devices, found {len(devices)}; use make_host_mesh() for "
             f"local runs")
     import numpy as np
-    return jax.sharding.Mesh(
-        np.asarray(devices[:need]).reshape(shape), axes,
-        **_mesh_kwargs(len(axes)))
+    return jax.sharding.Mesh(np.asarray(devices[:need]).reshape(shape), axes)
 
 
 def make_host_mesh(model: int = 1):
@@ -55,4 +44,4 @@ def make_host_mesh(model: int = 1):
             f"XLA_FLAGS=--xla_force_host_platform_device_count")
     return jax.sharding.Mesh(
         np.asarray(jax.devices()).reshape(n // model, model),
-        ("data", "model"), **_mesh_kwargs(2))
+        ("data", "model"))

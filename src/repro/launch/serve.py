@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import runtime
 from repro.configs.registry import ALL_ARCHS, get_config
 from repro.core.dual_lora import merge
 from repro.core.lora import init_adapters
@@ -266,12 +267,11 @@ def main(argv=None):
                          "= the unquantized pools, 'int8' = quantized "
                          "blocks with per-block scales (~1.78x blocks per "
                          "HBM byte; error-bound, not bitwise, vs f32)")
-    ap.add_argument("--paged-backend", default="jnp",
-                    choices=["jnp", "pallas"],
+    ap.add_argument("--paged-backend", default=None,
+                    choices=list(runtime.PAGED_BACKENDS),
                     help="continuous mode: paged-attention implementation — "
-                         "'jnp' gather oracle (CPU default) or 'pallas' "
-                         "kernels (interpret-mode on CPU; identical greedy "
-                         "tokens)")
+                         "'jnp' gather oracle or 'pallas' kernels; default "
+                         "the platform's (pallas on TPU, jnp on CPU)")
     ap.add_argument("--serve", action="store_true",
                     help="with --tenants: open-loop asyncio serving — "
                          "requests arrive on a synthetic trace at wall-"
@@ -309,6 +309,7 @@ def main(argv=None):
                          "round boundary and the updated client's prefix-"
                          "cache scope is invalidated by the version bump")
     args = ap.parse_args(argv)
+    runtime.enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.is_encdec:
@@ -453,7 +454,8 @@ def main(argv=None):
                   f"{stats['prefill_dispatches']} prefill + "
                   f"{stats['decode_dispatches']} decode dispatches, "
                   f"{stats['preemptions']} preemptions "
-                  f"[{stats['sched_policy']}, backend={sc.paged_backend}, "
+                  f"[{stats['sched_policy']}, "
+                  f"backend={stats['paged_backend']}, "
                   f"kv={sc.kv_dtype}]")
             if args.shards > 1:
                 print(f"  {args.shards} shards: placements "

@@ -7,7 +7,6 @@
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 from typing import Any, Callable, Dict, Optional
 
@@ -16,9 +15,6 @@ import jax.numpy as jnp
 from repro.models import encdec, model as dec
 
 Params = Dict[str, Any]
-
-# reusable no-op context for the mesh=None paths (nullcontext is stateless)
-_NULL_CTX = contextlib.nullcontext()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,32 +81,26 @@ class Model:
                      lora_scale: float = 1.0,
                      adapter_ids: Optional[jnp.ndarray] = None,
                      block_tables: Optional[jnp.ndarray] = None,
-                     paged_backend: Optional[str] = None,
-                     mesh: Optional[Any] = None):
+                     paged_backend: Optional[str] = None):
         """Chunked paged prefill: tokens (B, T) with n_new (B,) valid per
         row, scattered through block_tables at per-row offsets pos (B,).
         ``paged_backend`` overrides ``cfg.paged_backend`` ("jnp" | "pallas").
-        ``mesh`` (a ``jax.sharding.Mesh``) traces the step under the mesh so
-        the model's "data"-axis constraints bind batch rows to devices —
-        the serving engine instead enters the mesh around its jitted
-        dispatches (same effect, one context per chunk).  Returns
+        Traced under ``jax.set_mesh(mesh)``, the model's "data"-axis
+        constraints bind batch rows to devices.  Returns
         (logits (B, T, V), cache)."""
         if self.cfg.is_encdec:
             raise NotImplementedError("paged prefill is decoder-family only")
-        with mesh if mesh is not None else _NULL_CTX:
-            return dec.prefill_step(params, cache, tokens, pos, n_new,
-                                    self.cfg, adapters, lora_scale,
-                                    adapter_ids=adapter_ids,
-                                    block_tables=block_tables,
-                                    paged_backend=paged_backend)
+        return dec.prefill_step(params, cache, tokens, pos, n_new, self.cfg,
+                                adapters, lora_scale, adapter_ids=adapter_ids,
+                                block_tables=block_tables,
+                                paged_backend=paged_backend)
 
     def verify_step(self, params: Params, cache: Params, tokens, pos, n_new,
                     adapters: Optional[Params] = None,
                     lora_scale: float = 1.0,
                     adapter_ids: Optional[jnp.ndarray] = None,
                     block_tables: Optional[jnp.ndarray] = None,
-                    paged_backend: Optional[str] = None,
-                    mesh: Optional[Any] = None):
+                    paged_backend: Optional[str] = None):
         """Speculative-decoding verification: score a drafted chunk
         (feedback token + proposed continuation per row) causally against
         the paged cache.  This IS :meth:`prefill_step` — same scatter,
@@ -126,14 +116,13 @@ class Model:
                                  adapters=adapters, lora_scale=lora_scale,
                                  adapter_ids=adapter_ids,
                                  block_tables=block_tables,
-                                 paged_backend=paged_backend, mesh=mesh)
+                                 paged_backend=paged_backend)
 
     def decode_step(self, params: Params, cache: Params, tokens, pos,
                     adapters: Optional[Params] = None, lora_scale: float = 1.0,
                     adapter_ids: Optional[jnp.ndarray] = None,
                     block_tables: Optional[jnp.ndarray] = None,
-                    paged_backend: Optional[str] = None,
-                    mesh: Optional[Any] = None):
+                    paged_backend: Optional[str] = None):
         if self.cfg.is_encdec:
             if adapter_ids is not None or block_tables is not None:
                 raise NotImplementedError("multi-tenant banked adapters and "
@@ -141,12 +130,10 @@ class Model:
                                           "only")
             return encdec.decode_step(params, cache, tokens, pos, self.cfg,
                                       adapters, lora_scale)
-        with mesh if mesh is not None else _NULL_CTX:
-            return dec.decode_step(params, cache, tokens, pos, self.cfg,
-                                   adapters, lora_scale,
-                                   adapter_ids=adapter_ids,
-                                   block_tables=block_tables,
-                                   paged_backend=paged_backend)
+        return dec.decode_step(params, cache, tokens, pos, self.cfg,
+                               adapters, lora_scale, adapter_ids=adapter_ids,
+                               block_tables=block_tables,
+                               paged_backend=paged_backend)
 
 
 def get_model(cfg) -> Model:

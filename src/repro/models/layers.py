@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import runtime
 from repro.kernels import ops as kernel_ops
 from repro.kernels.paged_prefill import paged_scatter, paged_scatter_quant
 
@@ -39,24 +40,12 @@ def dt(name: str):
     return jnp.dtype(name)
 
 
-def _current_mesh():
-    """The mesh in scope, or None. jax 0.4.37 has no
-    ``jax.sharding.get_abstract_mesh``; fall back to the thread-resources
-    physical mesh (set by ``with Mesh(...)``)."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        return get_abstract()
-    try:
-        return jax.interpreters.pxla.thread_resources.env.physical_mesh
-    except AttributeError:
-        return None
-
-
 def maybe_shard(x: jnp.ndarray, spec: P) -> jnp.ndarray:
-    """with_sharding_constraint that no-ops when tracing without a mesh
-    (CPU smoke tests) or when the spec names axes the mesh lacks."""
-    mesh = _current_mesh()
-    if mesh is None or mesh.empty:
+    """with_sharding_constraint against the mesh set by ``jax.set_mesh``;
+    a no-op when tracing without one (single device), and axes the spec
+    names but the mesh lacks are dropped."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     axes = set(mesh.axis_names)
     fixed = []
@@ -318,12 +307,12 @@ def _sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, cfg,
 
 def _paged_attention_pallas(params, q, k, v, x, cfg, kv_cache, block_tables,
                             lengths, n_new, dn, la):
-    """The paged branch routed through the Pallas kernels
-    (``cfg.paged_backend == "pallas"``; ``ServeConfig.paged_backend``
-    threads it per stream).  Same scatter convention, same masks, same
-    outputs up to online-softmax accumulation order as the jnp gather
-    below — greedy token streams are bitwise-equal (tested), logits
-    allclose.  ``cfg.pallas_interpret=False`` compiles the kernels on TPU.
+    """The paged branch routed through the Pallas kernels (the TPU
+    default; ``ServeConfig.paged_backend`` / ``cfg.paged_backend`` pin
+    either backend).  Same scatter convention, same masks, same outputs
+    up to online-softmax accumulation order as the jnp gather below —
+    greedy token streams are bitwise-equal in the CPU tests, logits
+    allclose.  Compiled on TPU, interpreted on CPU.
 
     Decode steps (2-tuple ``paged``, S == 1) go through
     ``ops.paged_gqa_attention`` — the kernel's exclusive ``lengths`` is the
@@ -335,7 +324,6 @@ def _paged_attention_pallas(params, q, k, v, x, cfg, kv_cache, block_tables,
         raise NotImplementedError(
             "paged_backend='pallas' supports full attention only (no "
             "sliding window / logit softcap); use paged_backend='jnp'")
-    interp = cfg.pallas_interpret
     quant = "k_scale" in kv_cache             # int8 pools carry scale leaves
     if n_new is None and S == 1:
         if quant:
@@ -344,12 +332,12 @@ def _paged_attention_pallas(params, q, k, v, x, cfg, kv_cache, block_tables,
                 kv_cache["v_scale"], k, v, block_tables, lengths, None)
             o = kernel_ops.paged_gqa_attention(
                 q, kp, vp, block_tables, lengths + 1,
-                k_scale=ks, v_scale=vs, interpret=interp)
+                k_scale=ks, v_scale=vs)
         else:
             kp, vp = paged_scatter(kv_cache["k_pool"], kv_cache["v_pool"],
                                    k, v, block_tables, lengths, None)
             o = kernel_ops.paged_gqa_attention(
-                q, kp, vp, block_tables, lengths + 1, interpret=interp)
+                q, kp, vp, block_tables, lengths + 1)
     else:
         nn = (n_new if n_new is not None
               else jnp.full((B,), S, dtype=jnp.int32))
@@ -357,11 +345,11 @@ def _paged_attention_pallas(params, q, k, v, x, cfg, kv_cache, block_tables,
             o, kp, vp, ks, vs = kernel_ops.paged_prefill_gqa_attention(
                 q, k, v, kv_cache["k_pool"], kv_cache["v_pool"], block_tables,
                 lengths, nn, k_scale=kv_cache["k_scale"],
-                v_scale=kv_cache["v_scale"], interpret=interp)
+                v_scale=kv_cache["v_scale"])
         else:
             o, kp, vp = kernel_ops.paged_prefill_gqa_attention(
                 q, k, v, kv_cache["k_pool"], kv_cache["v_pool"], block_tables,
-                lengths, nn, interpret=interp)
+                lengths, nn)
     out = dn(o.astype(x.dtype).reshape(B, S, H * hd), params["wo"], la("wo"))
     new_cache = {"k_pool": kp, "v_pool": vp}
     if quant:
@@ -440,7 +428,7 @@ def multihead_attention(params: Params, x: jnp.ndarray, cfg,
         else:
             block_tables, lengths = paged             # (B, MB) i32, (B,) i32
             n_new = None
-        if cfg.paged_backend == "pallas":
+        if runtime.paged_backend(cfg.paged_backend) == "pallas":
             out, new_cache = _paged_attention_pallas(
                 params, q, k, v, x, cfg, kv_cache, block_tables, lengths,
                 n_new, dn, la)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import runtime
 from repro.models import layers as L
 from repro.models import mamba2, moe as moe_lib
 
@@ -273,9 +274,8 @@ def _resolve_backend(cfg, paged_backend: Optional[str]):
     object the attention layer already reads."""
     if paged_backend is None or paged_backend == cfg.paged_backend:
         return cfg
-    if paged_backend not in ("jnp", "pallas"):
-        raise ValueError(f"unknown paged_backend {paged_backend!r}")
-    return cfg.with_overrides(paged_backend=paged_backend)
+    return cfg.with_overrides(
+        paged_backend=runtime.paged_backend(paged_backend))
 
 
 def decode_step(params: Params, cache: Params, tokens: jnp.ndarray,
@@ -380,4 +380,4 @@ def _cached_scan(params: Params, cache: Params, tokens: jnp.ndarray,
     x = L.apply_norm(params["final_norm"], x, cfg.norm_type)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     logits = L.matmul(x, head.astype(dtype), out_dtype=jnp.float32)
-    return logits, {"blocks": new_caches}
+    return L.maybe_shard(logits, P("data", None, None)), {"blocks": new_caches}

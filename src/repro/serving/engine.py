@@ -32,7 +32,9 @@ from typing import Any, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import runtime
 from repro.core.lora import lora_scale
 from repro.serving.kv_cache import PagedKVCache, blocks_needed, reset_slot
 from repro.serving.registry import AdapterRegistry
@@ -74,11 +76,12 @@ class ServeConfig:
     #                                  arrival order + newest-first victims
     sched_aging: int = 16            # admission rounds queued per one-class
     #                                  promotion under "sla" (0 disables)
-    paged_backend: str = "jnp"       # paged-attention impl for the
+    paged_backend: Optional[str] = None  # paged-attention impl for the
     #                                  continuous path: "jnp" gather oracle
-    #                                  (CPU default) | "pallas" kernels
-    #                                  (interpret-mode on CPU; on TPU also
-    #                                  set cfg.pallas_interpret=False)
+    #                                  | "pallas" kernels; None = the model
+    #                                  config's choice, by default the
+    #                                  platform's (pallas on TPU, jnp on
+    #                                  CPU — repro.runtime.paged_backend)
     spec_decode: bool = False        # speculative decoding (continuous
     #                                  path): prompt-lookup self-drafts of
     #                                  up to spec_k tokens verified in ONE
@@ -98,13 +101,16 @@ class ServeConfig:
     #                                  dispatch per round.  1 (default) is
     #                                  the single-pool path, bit-identical
     #                                  to pre-shard behaviour.
-    mesh: Any = None                 # optional jax.sharding.Mesh entered
-    #                                  around device dispatches: activates
-    #                                  the "data"-axis sharding constraint
-    #                                  on the fused batch (slots are shard-
+    mesh: Any = None                 # optional jax.sharding.Mesh set
+    #                                  (jax.set_mesh) around device
+    #                                  dispatches: weights, adapter bank and
+    #                                  KV pool are placed replicated on it
+    #                                  and the fused batch splits over its
+    #                                  "data" axis (slots are shard-
     #                                  contiguous, so shard boundaries land
-    #                                  on device boundaries).  None = no
-    #                                  mesh (single device, the default).
+    #                                  on device boundaries; the Pallas
+    #                                  kernels run per device under
+    #                                  shard_map).  None = one device.
     overlap: bool = True             # async overlapped dispatch (continuous
     #                                  path): the host plans and enqueues
     #                                  chunk N+1 while the device executes
@@ -595,8 +601,19 @@ class StreamSession:
                 f"allocatable blocks {num_blocks - 1} not divisible by "
                 f"{sc.num_shards} shards (set num_blocks = 1 + "
                 f"{sc.num_shards}*k)")
+        if sc.mesh is not None and num_slots % sc.mesh.shape.get("data", 1):
+            raise ValueError(
+                f"{num_slots} slots do not split over the mesh's "
+                f"{sc.mesh.shape['data']}-way 'data' axis")
         self.kv, self.cache, self._reused = engine._paged_pool(
             num_slots, num_blocks, blocks_per, sc)
+        # with a mesh: weights, bank and pool replicated on its devices
+        # (a no-op for trees already placed there); the fused batch is
+        # split by the "data" constraints in models/model.py
+        self.params = self._place(engine.params)
+        self.cache = self._place(self.cache)
+        self.backend = runtime.paged_backend(sc.paged_backend
+                                             or engine.cfg.paged_backend)
         self._geom_key = (num_slots, sc.block_size, num_blocks, blocks_per,
                           sc.num_shards, sc.kv_dtype)
         # pool-lifetime counter; stats report the delta for this session
@@ -616,7 +633,7 @@ class StreamSession:
         if not self.open_loop:
             for r in requests:
                 self.submit(r)
-        self.bank = engine.registry.bank()
+        self.bank = self._place(engine.registry.bank())
         # hot-swap: the registry's bank_epoch moves when an online update
         # (re-)registers a client mid-serve; step() re-snapshots the bank
         # at its next round boundary.  Untouched clients' slots hold
@@ -636,11 +653,6 @@ class StreamSession:
         # its slot frees (and admits the queue head) at the next boundary.
         self.cap = (min(sc.scan_chunk, 8) if sc.eos_id is not None
                     else sc.scan_chunk)
-        # with a mesh, dispatches trace under it so the "data"-axis
-        # sharding constraints in models/layers.py bind the fused batch to
-        # devices; without one the constraints no-op (single-device path)
-        self._mesh = (sc.mesh if sc.mesh is not None
-                      else contextlib.nullcontext())
         # overlap fast path: device-resident plan state.  Block tables /
         # adapter ids are re-marshalled only when ``kv.table_version``
         # moves (admission, growth, rollback, release); lengths chain
@@ -717,7 +729,7 @@ class StreamSession:
             # every dispatch from here on.  A deferred (pipelined) chunk was
             # already dispatched under the old snapshot — its values are
             # unaffected by when we materialise them, so no flush needed.
-            self.bank = eng.registry.bank()
+            self.bank = self._place(eng.registry.bank())
             self._bank_epoch = epoch
             self.bank_refreshes += 1
         flushed: List[Tuple[int, List[int], bool]] = []
@@ -762,13 +774,13 @@ class StreamSession:
         bt, lens, ids = self._bt_dev, self._lens_dev, self._ids_dev
         if plan[0] == "prefill":
             arrs = sched.prefill_arrays(self.T)
-            with self._mesh:
+            with self._mesh():
                 sampled, self.cache, self._lens_dev, self.rng = (
                     eng._prefill_chunk(
-                        eng.params, self.bank, ids, self.cache,
+                        self.params, self.bank, ids, self.cache,
                         jnp.asarray(arrs["tokens"]), lens,
                         jnp.asarray(arrs["n_new"]), bt, self.rng,
-                        sc.temperature, backend=sc.paged_backend))
+                        sc.temperature, backend=self.backend))
             # THE overlap point: a chunk that emits no token has a sampled
             # array nothing will read (observe_prefill only indexes it for
             # feedback rows / completing prompts), so skip materialising it
@@ -785,12 +797,11 @@ class StreamSession:
             # bitwise-stable across scheduling mixes
             self.rng, _ = jax.random.split(self.rng)
             arrs = sched.verify_arrays(self.Tv)
-            with self._mesh:
+            with self._mesh():
                 greedy, self.cache = eng._verify_chunk(
-                    eng.params, self.bank, ids, self.cache,
+                    self.params, self.bank, ids, self.cache,
                     jnp.asarray(arrs["tokens"]), lens,
-                    jnp.asarray(arrs["n_new"]), bt,
-                    backend=sc.paged_backend)
+                    jnp.asarray(arrs["n_new"]), bt, backend=self.backend)
             # acceptance decides the advance/rollback amounts on host
             self._lens_ok, self._last_ok = False, False
             events = sched.observe_verify(arrs["n_new"], np.asarray(greedy),
@@ -803,12 +814,12 @@ class StreamSession:
             else:
                 st = sched.chunk_arrays()
                 last, act = jnp.asarray(st["last"]), jnp.asarray(st["active"])
-            with self._mesh:
+            with self._mesh():
                 (out, self.cache, self._lens_dev, self._last_dev,
                  self.rng) = eng._decode_chunk(
-                    eng.params, self.bank, ids, self.cache, last, act,
+                    self.params, self.bank, ids, self.cache, last, act,
                     lens, bt, jnp.int32(n), self.rng, sc.temperature,
-                    chunk_cap=self.cap, backend=sc.paged_backend)
+                    chunk_cap=self.cap, backend=self.backend)
             self._act_dev, self._last_ok = act, sc.overlap
             if self._pending is not None:
                 # this chunk is queued behind the deferred one, so
@@ -821,6 +832,23 @@ class StreamSession:
             events = sched.observe_chunk(np.asarray(out)[:n],
                                          eos_id=sc.eos_id)
         return flushed + events if flushed else events
+
+    # -- mesh --------------------------------------------------------------
+    def _mesh(self):
+        """Context for one dispatch: ``jax.set_mesh(sc.mesh)`` (the model's
+        "data" constraints then bind the fused batch to devices), or
+        nothing on one device.  A fresh object each time: ``set_mesh``
+        takes effect when constructed."""
+        if self.sc.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.sc.mesh)
+
+    def _place(self, tree):
+        """``tree`` replicated on the mesh's devices, or as is without a
+        mesh."""
+        if self.sc.mesh is None:
+            return tree
+        return jax.device_put(tree, NamedSharding(self.sc.mesh, P()))
 
     # -- deferred-observation plumbing ---------------------------------------
     def _growth_possible(self) -> bool:
@@ -895,6 +923,7 @@ class StreamSession:
                  "sched_policy": sc.sched_policy,
                  "num_shards": sc.num_shards,
                  "kv_dtype": sc.kv_dtype,
+                 "paged_backend": self.backend,
                  "overlap": sc.overlap,
                  "open_loop": self.open_loop,
                  # queue waits by class: wait_p50/p99 in admission rounds

@@ -3,6 +3,7 @@ extraction units."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from conftest import tiny_dense
 from repro.analysis import roofline as rl
@@ -87,23 +88,27 @@ def test_cost_analysis_is_per_device_and_scan_counts_once():
         y, _ = jax.lax.scan(body, x, None, length=4)
         return y
 
-    from repro.launch.dryrun import cost_dict
-
     x = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     w = jax.ShapeDtypeStruct((128, 128), jnp.float32)
-    rolled = cost_dict(jax.jit(f).lower(x, w).compile())["flops"]
-    unrolled = cost_dict(jax.jit(
-        lambda x, w: x @ w @ w @ w @ w).lower(x, w).compile())["flops"]
+    rolled = jax.jit(f).lower(x, w).compile().cost_analysis()["flops"]
+    unrolled = jax.jit(lambda x, w: x @ w @ w @ w @ w).lower(
+        x, w).compile().cost_analysis()["flops"]
     assert abs(unrolled - 4 * rolled) / unrolled < 0.05
 
 
 def test_roofline_terms_and_dominance():
     cost = {"flops": 197e12, "bytes accessed": 819e9 * 2}
-    roof = rl.analyze(cost, "", chips=4, model_flops=197e12 * 4)
+    roof = rl.analyze(cost, "", chips=4, model_flops=197e12 * 4,
+                      device_kind="TPU v5 lite")
     assert abs(roof.compute_s - 1.0) < 1e-6
     assert abs(roof.memory_s - 2.0) < 1e-6
     assert roof.dominant == "memory"
     assert abs(roof.useful_ratio - 1.0) < 1e-6
+
+
+def test_roofline_refuses_unknown_device_kind():
+    with pytest.raises(KeyError, match="no peak rates"):
+        rl.analyze({"flops": 1.0}, "", chips=1, device_kind="cpu")
 
 
 def test_collective_factors():

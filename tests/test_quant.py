@@ -4,10 +4,12 @@ KV-pool edge-case hardening.
 Parity discipline: the int8 serving path is NOT bitwise against f32 — it is
 held to (a) an exact contract between each Pallas kernel and the jnp
 dequantizing oracle fed the same int8 data, (b) a documented error bound
-between quantized and unquantized attention outputs, and (c) greedy
-token-stream equality on the smoke model across every serving feature
-(ragged batches, preemption, warm prefix reuse, spec decode, sharding) —
-argmax survives the quantization noise at these scales.
+between quantized and unquantized attention outputs, and (c) across
+every serving feature (ragged batches, preemption, warm prefix reuse, spec
+decode, sharding), logits within a stated bound of the unquantized
+reference's, with greedy streams that part from the unquantized stream
+only at a near-tie: random weights leave many top-2 margins below the
+quantization noise, so argmax equality alone holds by luck.
 """
 import subprocess
 import sys
@@ -41,6 +43,13 @@ RNG = np.random.default_rng(23)
 # quantization steps.  This is the documented error bound the int8 path is
 # held to against the f32 oracle.
 KV_ATOL = 0.05
+
+# int8 vs unquantized serving logits on the smoke model, whose logits have
+# a std of ~0.16.  int8 K/V (amax/127 per token and kv-head) moved them by
+# at most 0.0125, and by 0.020 with the int8 bank on top (CPU, jnp and
+# Pallas paths); the bound is 2x that.  Int4-coarse K/V (16x the step), or
+# a read that lost its scales or a block, lands near the logits' std.
+LOGIT_ATOL = 0.04
 
 
 def _rand(shape, dtype=jnp.float32, scale=1.0):
@@ -400,13 +409,103 @@ def _reqs(cfg):
             Request("c0", mk(8, 1), max_new_tokens=3)]
 
 
+def _contexts(reqs, outs):
+    """Per request: prompt + emitted tokens, and the prompt length."""
+    return [(np.concatenate([np.asarray(r.prompt, np.int32), o]),
+             len(r.prompt)) for r, o in zip(reqs, outs)]
+
+
+def _ref_logits(mt, reqs, ctxs):
+    """Reference: the unquantized model's dense full forward (no cache,
+    no paging) over each context, rows padded to one length — causal, so
+    padding never reaches a valid position.  (R, L, V) float32."""
+    from repro.core.lora import lora_scale
+    L = max(len(c) for c, _ in ctxs)
+    toks = np.zeros((len(ctxs), L), np.int32)
+    for i, (c, _) in enumerate(ctxs):
+        toks[i, :len(c)] = c
+    ids = jnp.asarray([mt.registry.acquire(r.client_id) for r in reqs],
+                      jnp.int32)
+    fwd = jax.jit(lambda p, t, a, i: mt.model.forward(
+        p, {"tokens": t}, adapters=a, lora_scale=lora_scale(mt.cfg),
+        adapter_ids=i)[0])
+    return np.asarray(fwd(mt.params, jnp.asarray(toks),
+                          mt.registry.bank(), ids), np.float32)
+
+
+def _paged_logits(mt, reqs, ctxs, kv_dtype, backend=None):
+    """The serving path's logits over each context: one chunked paged
+    prefill dispatch per row (``kv_dtype`` pools, ``backend`` kernels)."""
+    from repro.core.lora import lora_scale
+    bs = 4
+    L = max(len(c) for c, _ in ctxs)
+    mb = -(-L // bs)
+    R = len(ctxs)
+    toks = np.zeros((R, L), np.int32)
+    for i, (c, _) in enumerate(ctxs):
+        toks[i, :len(c)] = c
+    cache = mt.model.init_paged_decode_cache(R, 1 + R * mb, bs,
+                                             kv_dtype=kv_dtype)
+    ids = jnp.asarray([mt.registry.acquire(r.client_id) for r in reqs],
+                      jnp.int32)
+    logits, _ = mt.model.prefill_step(
+        mt.params, cache, jnp.asarray(toks), jnp.zeros((R,), jnp.int32),
+        jnp.asarray([len(c) for c, _ in ctxs], jnp.int32),
+        adapters=mt.registry.bank(), lora_scale=lora_scale(mt.cfg),
+        adapter_ids=ids,
+        block_tables=jnp.asarray(1 + np.arange(R * mb).reshape(R, mb),
+                                 jnp.int32),
+        paged_backend=backend)
+    return np.asarray(logits, np.float32)
+
+
+def _assert_near_argmax(ref, ctxs, what):
+    """Every emitted token scores within 2*LOGIT_ATOL of the reference's
+    best token at its position: what an int8 path whose logits sit within
+    LOGIT_ATOL of the reference can emit."""
+    for i, (c, p) in enumerate(ctxs):
+        lg = ref[i, p - 1:len(c) - 1]               # predicts c[p:]
+        gap = lg.max(-1) - lg[np.arange(len(lg)), c[p:]]
+        assert (gap <= 2 * LOGIT_ATOL).all(), (what, i, gap)
+
+
+def _check_int8_streams(mt_f, mt_q, reqs, ref_out, got_out, backend=None):
+    """int8 vs unquantized serving, held to logits and not to argmaxes
+    (greedy argmax flips on near-ties of random weights):
+
+    * both streams fill every request's budget;
+    * teacher-forced over the int8 stream, the int8 path's logits stay
+      within LOGIT_ATOL of the unquantized reference's;
+    * every token either stream emits is a near-argmax of the reference;
+    * the streams agree until the reference's top-2 margin first falls
+      within 2*LOGIT_ATOL — they part only on a near-tie.
+    """
+    for r, a, b in zip(reqs, ref_out, got_out):
+        budget = r.max_new_tokens
+        assert a.size == budget and b.size == budget
+    ctx_q, ctx_f = _contexts(reqs, got_out), _contexts(reqs, ref_out)
+    ref_q = _ref_logits(mt_f, reqs, ctx_q)
+    got = _paged_logits(mt_q, reqs, ctx_q, "int8", backend)
+    for i, (c, _) in enumerate(ctx_q):
+        np.testing.assert_allclose(got[i, :len(c)], ref_q[i, :len(c)],
+                                   rtol=0, atol=LOGIT_ATOL)
+    ref_f = _ref_logits(mt_f, reqs, ctx_f)
+    _assert_near_argmax(ref_q, ctx_q, "int8")
+    _assert_near_argmax(ref_f, ctx_f, "f32")
+    for i, ((c, p), a, b) in enumerate(zip(ctx_f, ref_out, got_out)):
+        top2 = np.sort(ref_f[i, p - 1:len(c) - 1], axis=-1)[:, -2:]
+        ties = np.flatnonzero(top2[:, 1] - top2[:, 0] <= 2 * LOGIT_ATOL)
+        agree = ties[0] if ties.size else a.size
+        np.testing.assert_array_equal(b[:agree], a[:agree])
+
+
 def _assert_stream_parity(cfg, sc_kw, bank_dtype="f32"):
     reqs = _reqs(cfg)
-    ref = _mt(cfg).generate(reqs, ServeConfig(**sc_kw))
-    got = _mt(cfg, bank_dtype=bank_dtype).generate(
-        reqs, ServeConfig(kv_dtype="int8", **sc_kw))
-    for r, o in zip(ref, got):
-        np.testing.assert_array_equal(o, r)
+    mt_f, mt_q = _mt(cfg), _mt(cfg, bank_dtype=bank_dtype)
+    ref = mt_f.generate(reqs, ServeConfig(**sc_kw))
+    got = mt_q.generate(reqs, ServeConfig(kv_dtype="int8", **sc_kw))
+    _check_int8_streams(mt_f, mt_q, reqs, ref, got,
+                        sc_kw.get("paged_backend"))
 
 
 @pytest.mark.parametrize("backend", ["jnp", "pallas"])
@@ -433,8 +532,7 @@ def test_int8_greedy_streams_match_with_warm_prefix_reuse():
     for rnd in range(2):                      # second round hits warm pool
         ref = mt_f.generate(reqs, ServeConfig(**kw))
         got = mt_q.generate(reqs, ServeConfig(kv_dtype="int8", **kw))
-        for r, o in zip(ref, got):
-            np.testing.assert_array_equal(o, r)
+        _check_int8_streams(mt_f, mt_q, reqs, ref, got)
     assert mt_q.last_stats["prefix_pool_reused"]
     assert mt_q.last_stats["prefix_hit_tokens"] > 0
     assert mt_q.last_stats["kv_dtype"] == "int8"

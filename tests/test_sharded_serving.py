@@ -313,16 +313,52 @@ def test_engine_sharded_geometry_validation(sharded_engine):
                     reason="needs >=2 devices (force with XLA_FLAGS="
                            "--xla_force_host_platform_device_count)")
 def test_engine_sharded_under_host_mesh_is_bitwise(sharded_engine):
-    """With a real 2-device host mesh entered around the dispatches, the
-    batch axis lays slots over "data" shard-contiguously — and the stream
-    stays bitwise equal to the meshless single-pool run."""
+    """With a real 2-device host mesh set around the dispatches, weights,
+    bank and pool sit replicated on both devices and the fused batch
+    splits over "data" (each device computes its own slots' logits rows,
+    the Pallas kernels per device under shard_map) — and the stream stays
+    bitwise equal to the meshless single-pool run on both backends."""
+    import jax.numpy as jnp
+    from repro.core.lora import lora_scale
     from repro.launch.mesh import make_host_mesh
 
     cfg, mt = sharded_engine
     reqs = _mixed_requests(cfg)
-    base = mt.generate(reqs, _sc(num_shards=1))
     mesh = make_host_mesh()
-    meshed = mt.generate(reqs, _sc(num_shards=2, mesh=mesh))
-    assert mt.last_stats["num_shards"] == 2
-    for a, b in zip(base, meshed):
-        np.testing.assert_array_equal(a, b)
+    for backend in ("jnp", "pallas"):
+        base = mt.generate(reqs, _sc(num_shards=1, paged_backend=backend))
+        ses = mt.session(_sc(num_shards=2, mesh=mesh,
+                             paged_backend=backend), reqs)
+        meshed = [[] for _ in reqs]
+        while ses.has_work:
+            for rid, toks, _ in ses.step():
+                meshed[rid].extend(toks)
+        ses.finalize()
+        assert mt.last_stats["num_shards"] == 2
+        assert mt.last_stats["paged_backend"] == backend
+        for a, b in zip(base, meshed):
+            np.testing.assert_array_equal(a, np.asarray(b, np.int32))
+        for leaf in jax.tree.leaves((ses.params, ses.bank, ses.cache)):
+            assert len(leaf.sharding.device_set) == 2
+
+        # one prefill dispatch under the mesh: logits rows split on "data"
+        K, T = 4, 4
+        cache = mt.model.init_paged_decode_cache(K, 25, 4)
+        args = (jnp.asarray(np.arange(K * T).reshape(K, T) % cfg.vocab_size,
+                            jnp.int32),
+                jnp.zeros((K,), jnp.int32), jnp.full((K,), T, jnp.int32))
+        kw = dict(adapters=mt.registry.bank(), lora_scale=lora_scale(cfg),
+                  adapter_ids=jnp.arange(K, dtype=jnp.int32),
+                  block_tables=jnp.arange(1, 25, dtype=jnp.int32)
+                  .reshape(K, 6))
+        step = jax.jit(mt.model.prefill_step,
+                       static_argnames=("paged_backend",))
+        kw["paged_backend"] = backend
+        want, _ = step(mt.params, cache, *args, **kw)
+        with jax.set_mesh(mesh):
+            got, _ = step(mt.params, cache, *args, **kw)
+        assert len(got.sharding.device_set) == 2
+        assert sorted(sh.index[0].start or 0
+                      for sh in got.addressable_shards) == [0, K // 2]
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
