@@ -1,0 +1,8 @@
+"""Median time to first token over every request due in the window, in
+ms from its scheduled arrival; a request that failed counts as never
+answered."""
+from chipbench.readers import percentile
+
+
+def read(ctx):
+    return percentile([r.ttft for r in ctx["records"]], 50, 1e3)
