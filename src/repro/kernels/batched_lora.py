@@ -215,6 +215,7 @@ def batched_lora_matmul(x, w, a, b, adapter_ids, scale: float = 1.0, *,
             pltpu.VMEM((bm, r_pad), jnp.float32),
         ],
         interpret=runtime.interpret_mode(),
+        name="batched_lora_matmul",
     )(*operands)
 
 
@@ -263,4 +264,5 @@ def batched_dual_lora_matmul(x, w, a1, b1, a2, b2, adapter_ids, fusion_w,
             pltpu.VMEM((bm, r_pad), jnp.float32),
         ],
         interpret=runtime.interpret_mode(),
+        name="batched_dual_lora_matmul",
     )(x, w, oh, fusion_w, a1p, b1p, a2, b2)

@@ -155,5 +155,6 @@ def paged_attention(q, k_pool, v_pool, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, hd), q.dtype),
         interpret=runtime.interpret_mode(),
+        name="paged_attention",
     )(block_tables, lengths, *operands)
     return out.reshape(B, H, hd)

@@ -229,6 +229,7 @@ def paged_prefill_attention(q, k_pool, v_pool, block_tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, T * G, hd), q.dtype),
         interpret=runtime.interpret_mode(),
+        name="paged_prefill_attention",
     )(block_tables, lengths, *operands)
     return (out.reshape(B, Kv, T, G, hd)
                .transpose(0, 2, 1, 3, 4)

@@ -133,11 +133,13 @@ class AsyncServer:
         loop = asyncio.get_running_loop()
         ses = self._ses
         while True:
-            while self._staged:                 # intake between rounds
-                req, arrival, fut = self._staged.popleft()
-                rid = ses.submit(req, arrival_time=arrival)
-                self._queues[rid] = asyncio.Queue()
-                fut.set_result(rid)
+            if self._staged:
+                with ses.span("intake"):        # intake between rounds
+                    while self._staged:
+                        req, arrival, fut = self._staged.popleft()
+                        rid = ses.submit(req, arrival_time=arrival)
+                        self._queues[rid] = asyncio.Queue()
+                        fut.set_result(rid)
             if not ses.has_work:
                 if self._closing:
                     break
@@ -145,12 +147,15 @@ class AsyncServer:
                 await self._wake.wait()
                 continue
             events = await loop.run_in_executor(None, ses.step)
-            for rid, toks, fin in events:
-                q = self._queues.get(rid)
-                if q is not None:
-                    q.put_nowait((list(toks), fin))
-                    if fin:
-                        self._queues.pop(rid, None)
+            if not events:
+                continue
+            with ses.span("deliver"):
+                for rid, toks, fin in events:
+                    q = self._queues.get(rid)
+                    if q is not None:
+                        q.put_nowait((list(toks), fin))
+                        if fin:
+                            self._queues.pop(rid, None)
         self.stats = ses.finalize()
 
 

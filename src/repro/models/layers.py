@@ -9,11 +9,14 @@ Conventions
 * Linear layers optionally take a LoRA adapter ``(A, B)``; the adapter path is
   ``y = x@W + (alpha/r) * (x@A)@B`` with the base weight frozen.
 * All matmuls accumulate in fp32 (``preferred_element_type``) and cast back.
+* The LoRA update, attention and the MLP run under ``jax.named_scope``s
+  ``lora``, ``attn`` and ``mlp``: op metadata only, so a profiler trace can
+  say which layer each device op belongs to.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -30,6 +33,18 @@ Params = Dict[str, Any]
 # to real mesh axes; "None" dims are replicated.
 MODEL = "model"
 DATA = "data"
+
+
+def _scoped(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``, a
+    fresh scope per call (so recursion nests)."""
+    def deco(fn):
+        @wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return deco
 
 
 # ---------------------------------------------------------------------------
@@ -63,6 +78,7 @@ def matmul(x, w, *, out_dtype=None):
     return y.astype(out_dtype or x.dtype)
 
 
+@_scoped("lora")
 def lora_delta(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
                adapter_ids: Optional[jnp.ndarray] = None,
                a_scale: Optional[jnp.ndarray] = None,
@@ -357,6 +373,7 @@ def _paged_attention_pallas(params, q, k, v, x, cfg, kv_cache, block_tables,
     return out, new_cache
 
 
+@_scoped("attn")
 def multihead_attention(params: Params, x: jnp.ndarray, cfg,
                         positions: jnp.ndarray,
                         adapters: Optional[Params] = None,
@@ -574,6 +591,7 @@ def mlp_specs(mlp_type: str) -> Params:
     return p
 
 
+@_scoped("mlp")
 def apply_mlp(params: Params, x: jnp.ndarray, mlp_type: str,
               adapters: Optional[Params] = None, lora_scale: float = 1.0,
               adapter_ids: Optional[jnp.ndarray] = None):

@@ -38,10 +38,13 @@ from repro import runtime
 from repro.core.lora import lora_scale
 from repro.serving.kv_cache import PagedKVCache, blocks_needed, reset_slot
 from repro.serving.registry import AdapterRegistry
-from repro.serving.scheduler import PRIORITY_CLASSES, Scheduler
+from repro.serving.scheduler import COUNTERS, PRIORITY_CLASSES, Scheduler
 from repro.serving.sharded import ShardedPagedKVCache, ShardedScheduler
 
 Params = Any
+
+# prefix of the host spans the serving path writes into a jax.profiler trace
+SPAN_PREFIX = "serve:"
 
 
 @dataclasses.dataclass
@@ -501,6 +504,9 @@ class StreamSession:
         finished)`` events the round produced (possibly none).
       * :meth:`finalize` — drain bookkeeping: builds ``engine.last_stats``
         and persists the warm prefix pool.  Idempotent.
+      * :meth:`counters` — the scheduler's cumulative counts, readable at
+        any moment; under a ``jax.profiler`` trace each step also writes
+        ``serve:`` host spans (:meth:`span`).
 
     **Overlapped dispatch** (``ServeConfig.overlap``, default True): device
     chunks are enqueued through jax async dispatch and the host only
@@ -683,6 +689,7 @@ class StreamSession:
                               and sc.eos_id is None and not sc.spec_decode
                               and not sc.prefix_cache)
         self._finalized = False
+        self.steps_run = 0               # step() calls: the spans' step=
 
     # -- intake --------------------------------------------------------------
     def submit(self, request: Request,
@@ -716,22 +723,51 @@ class StreamSession:
         """True while any request is queued or holds a slot."""
         return self.sched.has_work
 
+    def counters(self) -> dict:
+        """The scheduler's cumulative counts (``scheduler.COUNTERS``), cheap
+        enough to snapshot at any moment: the difference of two snapshots
+        is what the session did between them.  ``steps``: decode steps;
+        ``decode_row_steps``: active rows summed over them (occupancy is
+        ``decode_row_steps / (steps * batch_size)``); ``prefill_tokens``:
+        real tokens fed by prefill chunks; ``kv_grid_blocks`` and
+        ``kv_live_blocks``: the decode kernel's grid over table blocks
+        (slots x table width per step) and the part of it that holds live
+        K/V.  A deferred decode chunk counts when it is dispatched.  Only
+        the stepping thread writes the counts; a snapshot taken from
+        another thread while a step runs may see one chunk's counts half
+        applied."""
+        return {k: getattr(self.sched, k) for k in COUNTERS}
+
     # -- one engine round ----------------------------------------------------
+    def span(self, what: str, **args):
+        """A host span ``serve:<what>`` in a running ``jax.profiler``
+        trace, on the device ops' clock, tagged with this session's step
+        index; next to free when no trace is running."""
+        return jax.profiler.TraceAnnotation(SPAN_PREFIX + what,
+                                            step=self.steps_run, **args)
+
     def step(self) -> List[Tuple[int, List[int], bool]]:
         """Admission -> chunk planning -> device dispatch -> observation.
         Returns this round's ``(rid, new_tokens, finished)`` events ([] on
         an idle session).  Raises ``RuntimeError`` if queued work cannot
         make progress (a request that can never fit the pool)."""
+        self.steps_run += 1
+        with self.span("step"):
+            return self._step()
+
+    def _step(self) -> List[Tuple[int, List[int], bool]]:
         eng, sc, sched = self.engine, self.sc, self.sched
-        epoch = getattr(eng.registry, "bank_epoch", 0)
-        if epoch != self._bank_epoch:
-            # online update landed between rounds: swap in the new bank for
-            # every dispatch from here on.  A deferred (pipelined) chunk was
-            # already dispatched under the old snapshot — its values are
-            # unaffected by when we materialise them, so no flush needed.
-            self.bank = self._place(eng.registry.bank())
-            self._bank_epoch = epoch
-            self.bank_refreshes += 1
+        with self.span("bank_swap"):
+            epoch = getattr(eng.registry, "bank_epoch", 0)
+            if epoch != self._bank_epoch:
+                # online update landed between rounds: swap in the new bank
+                # for every dispatch from here on.  A deferred (pipelined)
+                # chunk was already dispatched under the old snapshot — its
+                # values are unaffected by when we materialise them, so no
+                # flush needed.
+                self.bank = self._place(eng.registry.bank())
+                self._bank_epoch = epoch
+                self.bank_refreshes += 1
         flushed: List[Tuple[int, List[int], bool]] = []
         if self._pending is not None and (
                 sched.queued or sched.prefill_pending
@@ -740,23 +776,102 @@ class StreamSession:
             # must land BEFORE admission or planning can preempt a slot
             # (preemption replays prompt+emitted, which must include them)
             flushed = self._flush_pending()
-        for slot, cid in sched.admit():
-            self.ids[slot] = eng.registry.acquire(cid)
-            self.cache = reset_slot(self.cache, slot)
-            self._ids_dev = None
-        plan = sched.prepare_chunk(self.T, self.cap)
+        with self.span("admit"):
+            for slot, cid in sched.admit():
+                self.ids[slot] = eng.registry.acquire(cid)
+                self.cache = reset_slot(self.cache, slot)
+                self._ids_dev = None
+        with self.span("plan"):
+            plan = sched.prepare_chunk(self.T, self.cap)
         if plan is None:
             if sched.has_work:           # nothing active: admit failed
                 raise RuntimeError("scheduler stalled with queued work")
             return flushed               # idle open-loop session
-        # marshal plan state.  Synchronous reference loop: rebuild device
-        # tables and ids every round.  Overlap fast path: reuse the cached
-        # device arrays while ``table_version`` stands still — on
-        # advance-only rounds (the steady decode state) the host ships only
-        # the chunk plan, and lengths come chained from the previous jit
-        # output instead of a fresh host->device copy.
+        kind = plan[0]
+        with self.span("tables"):
+            bt, lens, ids = self._marshal_tables()
+            if kind == "prefill":
+                arrs = sched.prefill_arrays(self.T)
+            elif kind == "verify":
+                arrs = sched.verify_arrays(self.Tv)
+            if kind != "decode":
+                feed = jnp.asarray(arrs["tokens"]), jnp.asarray(arrs["n_new"])
+            elif sc.overlap and self._last_ok:
+                feed = self._last_dev, self._act_dev
+            else:
+                st = sched.chunk_arrays()
+                feed = jnp.asarray(st["last"]), jnp.asarray(st["active"])
+        if kind == "prefill":
+            with self.span("dispatch", kind=kind), self._mesh():
+                sampled, self.cache, self._lens_dev, self.rng = (
+                    eng._prefill_chunk(
+                        self.params, self.bank, ids, self.cache, feed[0],
+                        lens, feed[1], bt, self.rng, sc.temperature,
+                        backend=self.backend))
+            # THE overlap point: a chunk that emits no token has a sampled
+            # array nothing will read (observe_prefill only indexes it for
+            # feedback rows / completing prompts), so skip materialising it
+            # — the host returns to planning the next chunk while this one
+            # is still executing on device.
+            if not sc.overlap or sched.chunk_emits(arrs["n_new"]):
+                with self.span("sync"):
+                    sampled = np.asarray(sampled)
+            self._last_ok = False        # completing prompts seed next_token
+            with self.span("observe"):
+                events = sched.observe_prefill(arrs["n_new"], sampled,
+                                               eos_id=sc.eos_id)
+        elif kind == "verify":
+            # keep the per-round rng consumption identical to the other
+            # chunk kinds (they split inside the jit) so streams stay
+            # bitwise-stable across scheduling mixes
+            self.rng, _ = jax.random.split(self.rng)
+            with self.span("dispatch", kind=kind), self._mesh():
+                greedy, self.cache = eng._verify_chunk(
+                    self.params, self.bank, ids, self.cache, feed[0], lens,
+                    feed[1], bt, backend=self.backend)
+            # acceptance decides the advance/rollback amounts on host
+            self._lens_ok, self._last_ok = False, False
+            with self.span("sync"):
+                greedy = np.asarray(greedy)
+            with self.span("observe"):
+                events = sched.observe_verify(arrs["n_new"], greedy,
+                                              eos_id=sc.eos_id)
+        else:
+            n = plan[1]
+            defer = self._defer_cfg_ok and sched.chunk_defer_safe(n)
+            last, act = feed
+            with self.span("dispatch", kind=kind), self._mesh():
+                (out, self.cache, self._lens_dev, self._last_dev,
+                 self.rng) = eng._decode_chunk(
+                    self.params, self.bank, ids, self.cache, last, act,
+                    lens, bt, jnp.int32(n), self.rng, sc.temperature,
+                    chunk_cap=self.cap, backend=self.backend)
+            self._act_dev, self._last_ok = act, sc.overlap
+            if self._pending is not None:
+                # this chunk is queued behind the deferred one, so
+                # materialising the latter's samples here overlaps with
+                # this chunk's device execution — the pipelining payoff
+                flushed = self._flush_pending()
+            if defer:
+                with self.span("observe"):
+                    self._pending = (out, n, sched.observe_chunk_counts(n))
+                return flushed
+            with self.span("sync"):
+                out = np.asarray(out)[:n]
+            with self.span("observe"):
+                events = sched.observe_chunk(out, eos_id=sc.eos_id)
+        return flushed + events if flushed else events
+
+    def _marshal_tables(self):
+        """Device block tables, lengths and adapter ids for the next
+        dispatch.  Synchronous reference loop: rebuilt every round.
+        Overlap fast path: the cached device arrays are reused while
+        ``table_version`` stands still — on advance-only rounds (the steady
+        decode state) the host ships only the chunk plan, and lengths come
+        chained from the previous jit output instead of a fresh
+        host->device copy."""
         ver = self.kv.table_version
-        if not sc.overlap or ver != self._tables_ver:
+        if not self.sc.overlap or ver != self._tables_ver:
             self._bt_dev, self._lens_dev = self.kv.device_tables()
             self._tables_ver, self._lens_ok = ver, True
             # any table move (admit/growth/rollback/release) can change the
@@ -771,67 +886,7 @@ class StreamSession:
             # earlier dispatch holding a (possibly zero-copy aliased)
             # view may still be queued — snapshot, never a live view
             self._ids_dev = jnp.asarray(self.ids.copy())
-        bt, lens, ids = self._bt_dev, self._lens_dev, self._ids_dev
-        if plan[0] == "prefill":
-            arrs = sched.prefill_arrays(self.T)
-            with self._mesh():
-                sampled, self.cache, self._lens_dev, self.rng = (
-                    eng._prefill_chunk(
-                        self.params, self.bank, ids, self.cache,
-                        jnp.asarray(arrs["tokens"]), lens,
-                        jnp.asarray(arrs["n_new"]), bt, self.rng,
-                        sc.temperature, backend=self.backend))
-            # THE overlap point: a chunk that emits no token has a sampled
-            # array nothing will read (observe_prefill only indexes it for
-            # feedback rows / completing prompts), so skip materialising it
-            # — the host returns to planning the next chunk while this one
-            # is still executing on device.
-            if not sc.overlap or sched.chunk_emits(arrs["n_new"]):
-                sampled = np.asarray(sampled)
-            self._last_ok = False        # completing prompts seed next_token
-            events = sched.observe_prefill(arrs["n_new"], sampled,
-                                           eos_id=sc.eos_id)
-        elif plan[0] == "verify":
-            # keep the per-round rng consumption identical to the other
-            # chunk kinds (they split inside the jit) so streams stay
-            # bitwise-stable across scheduling mixes
-            self.rng, _ = jax.random.split(self.rng)
-            arrs = sched.verify_arrays(self.Tv)
-            with self._mesh():
-                greedy, self.cache = eng._verify_chunk(
-                    self.params, self.bank, ids, self.cache,
-                    jnp.asarray(arrs["tokens"]), lens,
-                    jnp.asarray(arrs["n_new"]), bt, backend=self.backend)
-            # acceptance decides the advance/rollback amounts on host
-            self._lens_ok, self._last_ok = False, False
-            events = sched.observe_verify(arrs["n_new"], np.asarray(greedy),
-                                          eos_id=sc.eos_id)
-        else:
-            n = plan[1]
-            defer = self._defer_cfg_ok and sched.chunk_defer_safe(n)
-            if sc.overlap and self._last_ok:
-                last, act = self._last_dev, self._act_dev
-            else:
-                st = sched.chunk_arrays()
-                last, act = jnp.asarray(st["last"]), jnp.asarray(st["active"])
-            with self._mesh():
-                (out, self.cache, self._lens_dev, self._last_dev,
-                 self.rng) = eng._decode_chunk(
-                    self.params, self.bank, ids, self.cache, last, act,
-                    lens, bt, jnp.int32(n), self.rng, sc.temperature,
-                    chunk_cap=self.cap, backend=self.backend)
-            self._act_dev, self._last_ok = act, sc.overlap
-            if self._pending is not None:
-                # this chunk is queued behind the deferred one, so
-                # materialising the latter's samples here overlaps with
-                # this chunk's device execution — the pipelining payoff
-                flushed = self._flush_pending()
-            if defer:
-                self._pending = (out, n, sched.observe_chunk_counts(n))
-                return flushed
-            events = sched.observe_chunk(np.asarray(out)[:n],
-                                         eos_id=sc.eos_id)
-        return flushed + events if flushed else events
+        return self._bt_dev, self._lens_dev, self._ids_dev
 
     # -- mesh --------------------------------------------------------------
     def _mesh(self):
@@ -868,9 +923,12 @@ class StreamSession:
         """Materialise the deferred decode chunk (blocking on its dispatch;
         anything queued behind it keeps running) and fold its values into
         the scheduler — its events, one round late."""
-        out, n, slots = self._pending
-        self._pending = None
-        return self.sched.observe_chunk_values(slots, np.asarray(out)[:n])
+        with self.span("flush"):
+            out, n, slots = self._pending
+            self._pending = None
+            with self.span("sync"):
+                out = np.asarray(out)[:n]
+            return self.sched.observe_chunk_values(slots, out)
 
     # -- drain ---------------------------------------------------------------
     def finalize(self) -> dict:
@@ -900,9 +958,8 @@ class StreamSession:
                 entry["wait_wall_ms_p99"] = float(
                     np.percentile(walls, 99) * 1e3)
             classes[cname] = entry
-        stats = {"prefill_dispatches": sched.prefill_dispatches,
-                 "decode_dispatches": sched.decode_dispatches,
-                 "decode_steps": sched.steps,
+        counts = self.counters()
+        stats = {"decode_steps": counts.pop("steps"), **counts,
                  "spec_decode": sc.spec_decode,
                  "verify_dispatches": sched.verify_dispatches,
                  "drafted_tokens": sched.drafted_tokens,
@@ -911,11 +968,8 @@ class StreamSession:
                                      / max(1, sched.drafted_tokens)),
                  "rollback_tokens": sched.rollback_tokens,
                  "rollback_blocks": sched.rollback_blocks,
-                 "preemptions": sched.preemptions,
-                 "prompt_tokens": sched.prompt_tokens,
-                 "prefix_hit_tokens": sched.prefix_hit_tokens,
-                 "prefix_hit_rate": (sched.prefix_hit_tokens
-                                     / max(1, sched.prompt_tokens)),
+                 "prefix_hit_rate": (counts["prefix_hit_tokens"]
+                                     / max(1, counts["prompt_tokens"])),
                  "prefix_cached_blocks": kv.cached_blocks,
                  "prefix_evictions": kv.evicted_cached - self._evicted0,
                  "prefix_pool_reused": self._reused,
@@ -929,10 +983,7 @@ class StreamSession:
                  # queue waits by class: wait_p50/p99 in admission rounds
                  # (ticks); wait_wall_ms_* in wall-clock milliseconds when
                  # the session was driven open-loop with arrival times
-                 "classes": classes,
-                 "victim_sealed_fraction_mean": (
-                     float(np.mean(sched.victim_sealed_fractions))
-                     if sched.victim_sealed_fractions else 0.0)}
+                 "classes": classes}
         if sc.num_shards > 1:
             stats["shard_placements"] = dict(sched.placed)
         self.engine.last_stats = stats

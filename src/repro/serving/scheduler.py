@@ -73,6 +73,14 @@ PRIORITY_CLASSES: Dict[str, int] = {
     "interactive": 0, "batch": 1, "background": 2}
 _LEVEL_NAMES = {v: k for k, v in PRIORITY_CLASSES.items()}
 
+# the cumulative counts a serving session exposes at any moment
+# (``StreamSession.counters``): a window's figures are the difference of two
+# snapshots
+COUNTERS = ("steps", "decode_dispatches", "prefill_dispatches",
+            "prompt_tokens", "prefix_hit_tokens", "preemptions",
+            "decode_row_steps", "prefill_tokens", "kv_grid_blocks",
+            "kv_live_blocks")
+
 
 @dataclasses.dataclass(frozen=True)
 class VictimInfo:
@@ -259,6 +267,18 @@ class Scheduler:
         self.prompt_tokens = 0              # prompt tokens admitted (incl.
         #                                     preemption replays)
         self.prefix_hit_tokens = 0          # of those, served from cache
+        self.decode_row_steps = 0           # active rows summed over decode
+        #                                     steps (occupancy = this over
+        #                                     steps x slots)
+        self.prefill_tokens = 0             # real (non-pad) tokens fed by
+        #                                     prefill chunks
+        self.kv_grid_blocks = 0             # slots x table width summed over
+        #                                     decode steps: the decode
+        #                                     kernel's grid over blocks, per
+        #                                     layer and KV head
+        self.kv_live_blocks = 0             # of those, blocks holding live
+        #                                     K/V: ceil(attended length /
+        #                                     block size) per active row
 
     # ---- intake -----------------------------------------------------------
     def submit(self, rid: int, client_id: Any, prompt, budget: int,
@@ -673,6 +693,7 @@ class Scheduler:
                     self._finish(slot)
                 events.append((rid, [tok], done))
         self.prefill_dispatches += 1
+        self.prefill_tokens += int(np.sum(n_new))
         return events
 
     # ---- verify chunks (speculative decoding) ------------------------------
@@ -790,6 +811,7 @@ class Scheduler:
         and recorded the first emission), so step t of slot i fed the
         previous sample and ``sampled[t, i]`` is always an emission."""
         n = sampled.shape[0]
+        self._count_decode(self.active_slots, n)
         events = []
         for slot, st in enumerate(self._slots):
             if st is None:
@@ -818,9 +840,23 @@ class Scheduler:
             else:
                 st.next_token = int(sampled[n - 1, slot])
                 events.append((st.rid, new_toks, False))
+        return events
+
+    def _count_decode(self, slots: List[int], n: int) -> None:
+        """Count an ``n``-step decode chunk over the active ``slots``, from
+        the pool's lengths BEFORE the chunk advances them: step ``t`` of a
+        row holding ``L`` tokens writes position ``L + t`` and attends
+        ``L + t + 1``.  Every row stays on the device for all ``n`` steps
+        (the chunk's active mask is fixed), even one that finishes early."""
+        kv = self.kv
         self.steps += n
         self.decode_dispatches += 1
-        return events
+        self.decode_row_steps += len(slots) * n
+        self.kv_grid_blocks += kv.num_slots * kv.max_blocks_per_slot * n
+        if slots:
+            ends = (kv.lengths[slots].astype(np.int64)[:, None]
+                    + np.arange(1, n + 1))
+            self.kv_live_blocks += int((-(-ends // kv.block_size)).sum())
 
     # ---- deferred observation (overlap pipelining) -------------------------
     def chunk_defer_safe(self, n: int) -> bool:
@@ -844,17 +880,14 @@ class Scheduler:
         held at plan time and that prefix sealing is off (``advance`` gets
         no tokens).  Returns the participating slot ids, to be replayed
         through :meth:`observe_chunk_values` once the samples land."""
-        slots = []
-        for slot, st in enumerate(self._slots):
-            if st is None:
-                continue
+        slots = self.active_slots
+        self._count_decode(slots, n)
+        for slot in slots:
+            st = self._slots[slot]
             assert st.fed >= st.prompt.size, \
                 f"slot {slot} entered a decode chunk mid-prefill"
             st.fed += n
             self.kv.advance(slot, n)
-            slots.append(slot)
-        self.steps += n
-        self.decode_dispatches += 1
         return slots
 
     def observe_chunk_values(self, slots: List[int], sampled: np.ndarray

@@ -583,6 +583,23 @@ class ShardedScheduler:
         return sum(sh.prefix_hit_tokens for sh in self.shards)
 
     @property
+    def decode_row_steps(self) -> int:
+        return sum(sh.decode_row_steps for sh in self.shards)
+
+    @property
+    def prefill_tokens(self) -> int:
+        return sum(sh.prefill_tokens for sh in self.shards)
+
+    # each shard counts its own rows of the fused decode grid
+    @property
+    def kv_grid_blocks(self) -> int:
+        return sum(sh.kv_grid_blocks for sh in self.shards)
+
+    @property
+    def kv_live_blocks(self) -> int:
+        return sum(sh.kv_live_blocks for sh in self.shards)
+
+    @property
     def preemptions_by_class(self) -> Dict[str, int]:
         merged: Dict[str, int] = {}
         for sh in self.shards:

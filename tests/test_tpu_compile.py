@@ -9,6 +9,8 @@ do not fit its memory, none of which interpret mode sees.  Nothing runs.
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -98,6 +100,32 @@ def test_paged_prefill_kernel_compiles_for_v5e(one_chip, quant):
              ops.paged_prefill_gqa_attention(q, k, v, kp, vp, bt, ln, nn,
                                              k_scale=ks, v_scale=vs)[0],
              q, kn, kn, kp, vp, bt, ln, ln, ks, vs)
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention",
+                                    "paged_prefill_attention"])
+def test_kernel_instructions_keep_their_wrapper_names(one_chip, kernel):
+    """The chip benchmark finds each paged kernel by its HLO instruction
+    name (``%paged_attention.<n>``): the kernel's own ``name=`` must leave
+    that name as it is."""
+    (kp, vp), _ = _pools(one_chip, False)
+    hd, H, Kv = (CONFIG.resolved_head_dim, CONFIG.n_heads,
+                 CONFIG.n_kv_heads)
+    bt, ln = _tables(one_chip)
+    if kernel == "paged_attention":
+        q = _spec((SLOTS, 1, H, hd), jnp.bfloat16, one_chip)
+        compiled = _compile(lambda q, kp, vp, bt, ln: ops.paged_gqa_attention(
+            q, kp, vp, bt, ln), q, kp, vp, bt, ln)
+    else:
+        q = _spec((SLOTS, CHUNK, H, hd), jnp.bfloat16, one_chip)
+        kn = _spec((SLOTS, CHUNK, Kv, hd), jnp.bfloat16, one_chip)
+        compiled = _compile(
+            lambda q, k, v, kp, vp, bt, ln, nn:
+            ops.paged_prefill_gqa_attention(q, k, v, kp, vp, bt, ln, nn)[0],
+            q, kn, kn, kp, vp, bt, ln, ln)
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="
+                       r"\"tpu_custom_call\"", compiled.as_text(), re.M)
+    assert calls and all(c.startswith(kernel + ".") for c in calls), calls
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
