@@ -13,7 +13,7 @@ stays inside the kernel).
 Query ``t`` of row ``b`` sits at absolute position ``lengths[b] + t`` and
 attends positions ``[0, lengths[b] + t]`` — prior context plus a causal
 mask *inside* the chunk — which is exactly the per-row mask applied to the
-running online-softmax.  Layout mirrors the decode kernel: GQA folds the
+running online-softmax.  Layout: GQA folds the
 chunk and the group axis into one query tile ``(T*G, hd)`` (row ``r``
 holds chunk position ``r // G``), grid ``(B, Kv, MB)`` with the block loop
 innermost carrying flash-style running max / denominator / accumulator

@@ -273,12 +273,13 @@ class Scheduler:
         self.prefill_tokens = 0             # real (non-pad) tokens fed by
         #                                     prefill chunks
         self.kv_grid_blocks = 0             # slots x table width summed over
-        #                                     decode steps: the decode
-        #                                     kernel's grid over blocks, per
-        #                                     layer and KV head
+        #                                     decode steps: the table blocks
+        #                                     the decode kernel's grid
+        #                                     covers, per layer
         self.kv_live_blocks = 0             # of those, blocks holding live
         #                                     K/V: ceil(attended length /
-        #                                     block size) per active row
+        #                                     block size) per active row,
+        #                                     the blocks the kernel fetches
 
     # ---- intake -----------------------------------------------------------
     def submit(self, rid: int, client_id: Any, prompt, budget: int,

@@ -9,7 +9,8 @@ import pytest
 from conftest import tiny_dense, tiny_ssm
 from repro.core.lora import init_adapters
 from repro.kernels.ops import paged_gqa_attention, paged_prefill_gqa_attention
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (blocks_per_step, dma_heads,
+                                           paged_attention)
 from repro.kernels.paged_prefill import paged_prefill_attention
 from repro.kernels.ref import paged_attention_ref, paged_prefill_attention_ref
 from repro.models.api import get_model
@@ -26,17 +27,30 @@ RNG = np.random.default_rng(11)
 # Paged-attention kernel vs the gather-materialising oracle
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("H,Kv", [(4, 4), (8, 2)])
+@pytest.mark.parametrize("MB,hd,T", [pytest.param(32, 32, 16, id="full"),
+                                      pytest.param(12, 32, 4, id="narrow"),
+                                      pytest.param(12, 64, 4, id="hd64")])
+@pytest.mark.parametrize("H,Kv", [(4, 4), (8, 2), (16, 16), (32, 4)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_attention_matches_ref(H, Kv, dtype):
-    B, hd, NB, bs, MB = 5, 32, 11, 8, 4
+def test_paged_attention_matches_ref(MB, hd, T, H, Kv, dtype):
+    """MHA (G = 1), GQA up to G = 8 with Kv = 4; rows empty, of one
+    position, of one block, either side of a grid step's T blocks and of
+    the whole table; a table whose width halves the blocks per step; and
+    hd 64 through the model-layout wrapper, which pads it to 128."""
+    bs = 8
+    assert blocks_per_step(MB, bs, dma_heads(Kv, 2), 128, 2) == T
+    lens = [0, 1, bs, T * bs - 1, T * bs, T * bs + 1, 19, MB * bs]
+    B, NB = len(lens), len(lens) * MB + 1
     q = jnp.asarray(RNG.standard_normal((B, H, hd)), dtype)
     kp = jnp.asarray(RNG.standard_normal((NB, bs, Kv, hd)), dtype)
     vp = jnp.asarray(RNG.standard_normal((NB, bs, Kv, hd)), dtype)
     bt = jnp.asarray(np.stack([RNG.permutation(NB)[:MB] for _ in range(B)]),
                      jnp.int32)                      # scattered physical ids
-    lens = jnp.asarray([0, 1, 7, 19, 32], jnp.int32)  # ragged, incl. empty
-    y = paged_attention(q, kp, vp, bt, lens)
+    lens = jnp.asarray(lens, jnp.int32)
+    if hd == 64:                                     # through the wrapper
+        y = paged_gqa_attention(q[:, None], kp, vp, bt, lens)[:, 0]
+    else:
+        y = paged_attention(q, kp, vp, bt, lens)
     yr = paged_attention_ref(q, kp, vp, bt, lens)
     atol = 0.03 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(y, np.float32),

@@ -23,7 +23,8 @@ from conftest import tiny_dense
 from repro.core.lora import init_adapters
 from repro.kernels.ops import (batched_lora_dense, paged_gqa_attention,
                                paged_prefill_gqa_attention)
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (blocks_per_step, dma_heads,
+                                           paged_attention)
 from repro.kernels.paged_prefill import (paged_prefill_attention,
                                          paged_scatter, paged_scatter_quant)
 from repro.kernels.quant import dequantize_int8, quantize_int8
@@ -90,21 +91,33 @@ def _quant_pools(NB, bs, Kv, hd):
     return kf, vf, kq, ks, vq, vs
 
 
-@pytest.mark.parametrize("H,Kv", [(4, 4), (8, 2)])
-def test_paged_attention_int8_matches_dequant_oracle(H, Kv):
+@pytest.mark.parametrize("MB,hd,T", [pytest.param(32, 32, 16, id="full"),
+                                      pytest.param(12, 32, 4, id="narrow"),
+                                      pytest.param(12, 64, 4, id="hd64")])
+@pytest.mark.parametrize("H,Kv", [(4, 4), (8, 2), (16, 16), (32, 4)])
+def test_paged_attention_int8_matches_dequant_oracle(MB, hd, T, H, Kv):
     """Kernel vs the jnp oracle fed the SAME int8 blocks: tight tolerance
-    (both dequantize identically; only accumulation order differs)."""
-    B, hd, NB, bs, MB = 5, 32, 11, 8, 4
+    (both dequantize identically; only accumulation order differs).  Rows
+    either side of a grid step's T blocks, a table that halves the blocks
+    per step, and hd 64 through the wrapper (padded to 128 lanes)."""
+    bs = 8
+    assert blocks_per_step(MB, bs, dma_heads(Kv, 1), 128, 1) == T
+    lens = [0, 1, bs, T * bs - 1, T * bs, T * bs + 1, 19, MB * bs]
+    B, NB = len(lens), len(lens) * MB + 1
     kf, vf, kq, ks, vq, vs = _quant_pools(NB, bs, Kv, hd)
     q = _rand((B, H, hd))
     bt = jnp.asarray(np.stack([RNG.permutation(NB)[:MB] for _ in range(B)]),
                      jnp.int32)
-    lens = jnp.asarray([0, 1, 7, 19, 32], jnp.int32)
-    pad = [(0, 0)] * 3 + [(0, 128 - hd)]
-    y = paged_attention(jnp.pad(q, [(0, 0), (0, 0), (0, 128 - hd)]),
-                        jnp.pad(kq, pad), jnp.pad(vq, pad), bt, lens,
-                        k_scale=ks, v_scale=vs,
-                        scale=hd ** -0.5)[..., :hd]
+    lens = jnp.asarray(lens, jnp.int32)
+    if hd == 64:
+        y = paged_gqa_attention(q[:, None], kq, vq, bt, lens,
+                                k_scale=ks, v_scale=vs)[:, 0]
+    else:
+        pad = [(0, 0)] * 3 + [(0, 128 - hd)]
+        y = paged_attention(jnp.pad(q, [(0, 0), (0, 0), (0, 128 - hd)]),
+                            jnp.pad(kq, pad), jnp.pad(vq, pad), bt, lens,
+                            k_scale=ks, v_scale=vs,
+                            scale=hd ** -0.5)[..., :hd]
     yr = paged_attention_ref(q, kq, vq, bt, lens, k_scale=ks, v_scale=vs)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=2e-5)
     # and within the quantization error bound of the UNQUANTIZED pools

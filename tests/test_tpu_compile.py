@@ -89,6 +89,57 @@ def test_paged_decode_kernel_compiles_for_v5e(one_chip, quant):
         q, kp, vp, *_tables(one_chip), ks, vs)
 
 
+# (B, H, Kv, NB, MB) of the chip benchmark's two cells, head_dim 128
+CELL_SHAPES = {"olmo-1b": (20, 16, 16, 1801, 128),
+               "yi-6b-l16": (32, 32, 4, 3073, 96)}
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_paged_decode_kernel_reads_the_pool_in_place(one_chip, cell, quant):
+    """At the cells' shapes no instruction but the decode kernel's own
+    custom call takes a whole pool as an operand: no transpose, copy or
+    staging of the pool on the way in."""
+    B, H, Kv, NB, MB = CELL_SHAPES[cell]
+    pool = (NB, BLOCK, Kv, 128)
+    dtype = jnp.int8 if quant else jnp.bfloat16
+    kp = _spec(pool, dtype, one_chip)
+    ks = _spec(pool[:3], jnp.float32, one_chip) if quant else None
+    compiled = _compile(
+        lambda q, kp, vp, bt, ln, ks, vs: ops.paged_gqa_attention(
+            q, kp, vp, bt, ln, k_scale=ks, v_scale=vs),
+        _spec((B, 1, H, 128), jnp.bfloat16, one_chip), kp, kp,
+        _spec((B, MB), jnp.int32, one_chip), _spec((B,), jnp.int32, one_chip),
+        ks, ks)
+    lines = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*)$",
+                       compiled.as_text(), re.M)
+    dims = {name: tuple(int(d) for d in m.group(1).split(","))
+            for name, rest in lines
+            if (m := re.match(r"\(?\w+\[([\d,]+)\]", rest))}
+    readers = {name for name, rest in lines
+               if any(dims.get(o) == pool
+                      for o in re.findall(r"%([\w.\-]+)", rest))}
+    assert readers and all(r.startswith("paged_attention.")
+                           for r in readers), readers
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("kv", [1, 2, 3, 6, 12])
+def test_paged_decode_kernel_compiles_at_any_kv_head_count(one_chip, kv,
+                                                            quant):
+    """``dma_heads`` pads a KV axis that cannot DMA as it lies: every
+    head count compiles, padded or not."""
+    nb, mb = 1 + SLOTS * 16, 16
+    dtype = jnp.int8 if quant else jnp.bfloat16
+    kp = _spec((nb, BLOCK, kv, 128), dtype, one_chip)
+    ks = _spec((nb, BLOCK, kv), jnp.float32, one_chip) if quant else None
+    _compile(lambda q, kp, vp, bt, ln, ks, vs: ops.paged_gqa_attention(
+        q, kp, vp, bt, ln, k_scale=ks, v_scale=vs),
+        _spec((SLOTS, 1, 2 * kv, 128), jnp.bfloat16, one_chip), kp, kp,
+        _spec((SLOTS, mb), jnp.int32, one_chip),
+        _spec((SLOTS,), jnp.int32, one_chip), ks, ks)
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_paged_prefill_kernel_compiles_for_v5e(one_chip, quant):
     (kp, vp), (ks, vs) = _pools(one_chip, quant)
