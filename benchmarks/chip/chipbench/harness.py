@@ -4,7 +4,8 @@ The system under test is the program's serving path as a user drives it:
 ``repro.launch.serve.AsyncServer`` over an open-loop ``StreamSession`` of
 ``MultiTenantEngine``, with an ``AdapterRegistry`` of Eq. 7-fused clients
 (``register_dual``).  This module is the only one that imports the
-program.
+program; everything specific to the model's architecture comes from the
+module its configuration file names (:func:`chipbench.spec.arch_of`).
 
 :func:`run_cell` does everything after the look for a chip, so the tests
 drive it on the CPU at a tiny size (with faults planted through
@@ -28,9 +29,8 @@ import jax
 import numpy as np
 
 from chipbench import reference as R
-from chipbench import spec, traces
+from chipbench import serve_trace, spec, traces
 from chipbench import traffic as TR
-from chipbench import weights as W
 from chipbench import work
 
 sys.path.insert(0, str(spec.ROOT / "src"))
@@ -40,13 +40,8 @@ sys.path.insert(0, str(spec.ROOT / "src"))
 GRACE_S = 60.0
 # the traced span: this share of the window, centred, once the slots fill
 TRACE_SPAN = (0.4, 0.6)
-# program (XLA module) names and the paged-attention kernels inside them
+# the program's (XLA modules') names
 PROGRAMS = {"prefill": "_prefill_chunk_impl", "decode": "_decode_chunk_impl"}
-# (the HLO instruction of each Pallas call is named after its wrapper)
-KERNELS = {"paged_prefill": {"module": "_prefill_chunk_impl",
-                             "op": "%paged_prefill_attention."},
-           "paged_attention": {"module": "_decode_chunk_impl",
-                               "op": "%paged_attention."}}
 
 
 def log(msg: str) -> None:
@@ -89,31 +84,11 @@ class CompileWatch:
 
 
 def program_config(config: dict):
-    """The program's ModelConfig for a configuration file.  The program
-    scales a tied embedding by sqrt(d_model) and normalises with eps 1e-6
-    whatever the model; where the source differs, the file lists the key
-    under ``program_departures`` with the value the program runs."""
+    """The program's ModelConfig for a configuration file, as its
+    architecture module maps it."""
     from repro.configs.base import ModelConfig
 
-    c = config
-    tied = bool(c["tie_word_embeddings"])
-    want = c["hidden_size"] ** 0.5 if tied else 1.0
-    mult, eps = spec.as_run(c, "embed_multiplier"), spec.as_run(c, "norm_eps")
-    if abs(mult - want) > 1e-9 * want or eps != 1e-6:
-        raise ValueError(f"{c['name']}: the program runs embed multiplier "
-                         f"{want} and norm eps 1e-6; the file says "
-                         f"{mult} and {eps}")
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], d_ff=c["intermediate_size"],
-        vocab_size=c["vocab_size"], head_dim=c["head_dim"],
-        norm_type={"rmsnorm": "rmsnorm",
-                   "layernorm_nonparametric": "nonparametric"}[c["norm"]],
-        mlp_type="swiglu", tie_embeddings=tied, rope_theta=c["rope_theta"],
-        max_seq_len=c["max_position_embeddings"],
-        lora_rank=c["lora"]["rank"], lora_alpha=c["lora"]["alpha"],
-        lora_targets=tuple(c["lora"]["targets"]))
+    return ModelConfig(**spec.arch_of(config).program_config_kwargs(config))
 
 
 def build(config: dict, traffic: dict, seed: int):
@@ -123,13 +98,13 @@ def build(config: dict, traffic: dict, seed: int):
     from repro.serving.engine import MultiTenantEngine, ServeConfig
     from repro.serving.registry import AdapterRegistry
 
+    arch = spec.arch_of(config)
     cfg = program_config(config)
-    d = W.dims(config)
-    params = W.init_params(d, seed)
+    params = arch.init_params(config, seed)
     n = TR.n_clients(traffic)
     reg = AdapterRegistry(cfg, capacity=n)
     for c in range(n):
-        per, glo, w = W.client_adapters(d, seed, c)
+        per, glo, w = arch.client_adapters(config, seed, c)
         reg.register_dual(f"c{c}", per, glo, w)
     eng = MultiTenantEngine(get_model(cfg), cfg, params, reg)
     srv = config["serving"]
@@ -174,32 +149,18 @@ async def warm_up(eng, sc, traffic: dict) -> None:
             for c in range(sc.batch_size)])
 
 
-def _span_hooks(srv):
-    """Host spans around the calls into the session and the scheduler."""
-    ses = srv._ses
-
-    def wrap(obj, name, label):
-        fn = getattr(obj, name)
-
-        def spanned(*a, **k):
-            with jax.profiler.TraceAnnotation(traces.HOST_PREFIX + label):
-                return fn(*a, **k)
-        setattr(obj, name, spanned)
-
-    wrap(ses, "step", "session.step")
-    wrap(ses.sched, "prepare_chunk", "scheduler.prepare_chunk")
-    wrap(ses.sched, "admit", "scheduler.admit")
-
-
-async def _trace_span(t0: float, seconds: float, out: dict):
-    """Profile [t0 + a*seconds, t0 + b*seconds) of the window."""
+async def _trace_span(srv, t0: float, seconds: float, out: dict):
+    """Profile [t0 + a*seconds, t0 + b*seconds) of the window, and take
+    the difference of the session's counters over it."""
     a, b = TRACE_SPAN
     await asyncio.sleep(max(0.0, t0 + a * seconds - time.perf_counter()))
     out["dir"] = tempfile.mkdtemp(prefix="chipbench-trace-")
     jax.profiler.start_trace(out["dir"])
     out["t0"] = time.perf_counter()
+    before = srv._ses.counters()
     with jax.profiler.TraceAnnotation(traces.HOST_PREFIX + "window"):
         await asyncio.sleep(max(0.0, t0 + b * seconds - time.perf_counter()))
+    out["counters"] = serve_trace.counter_delta(before, srv._ses.counters())
     out["t1"] = time.perf_counter()
     jax.profiler.stop_trace()
 
@@ -226,8 +187,6 @@ async def serve_window(eng, sc, traffic: dict, items, seconds: float,
 
     srv = AsyncServer(eng, sc).start()
     tr: dict = {}
-    if trace:
-        _span_hooks(srv)
     recs: List[Rec] = []
     tasks = []
     loop = traffic["loop"]
@@ -267,7 +226,8 @@ async def serve_window(eng, sc, traffic: dict, items, seconds: float,
         tasks = [asyncio.ensure_future(user(u))
                  for u in range(traffic["users"])]
     if trace:
-        tasks.append(asyncio.ensure_future(_trace_span(t0, seconds, tr)))
+        tasks.append(asyncio.ensure_future(_trace_span(srv, t0, seconds,
+                                                       tr)))
     if loop != "open":
         # the close ends a closed-loop or resident run: requests still in
         # flight are cut off, not waited for (the session is abandoned,
@@ -332,7 +292,8 @@ def check(config: dict, traffic: dict, seed: int, recs: List[Rec],
     seqs = [(r.client, np.concatenate([r.prompt,
                                        np.asarray(r.tokens[:-1], np.int32)]))
             for r in sample]
-    ref = R.Reference(config, seed)
+    arch = spec.arch_of(config)
+    ref = arch.Reference(config, seed)
     hs = ref.hidden(seqs, length)
     head = ref.head()
     gaps = [R.served_gaps(h, head, r.prompt_len, np.asarray(r.tokens))
@@ -342,7 +303,7 @@ def check(config: dict, traffic: dict, seed: int, recs: List[Rec],
            "max_logit_gap": float(max((g.max() for g in gaps),
                                       default=math.inf))}
     if control:
-        ctl = R.Reference(config, seed, quant=True).hidden(seqs, length)
+        ctl = arch.Reference(config, seed, quant=True).hidden(seqs, length)
         cg = [R.control_gaps(h, c, head, r.prompt_len, len(r.tokens))
               for h, c, r in zip(hs, ctl, sample)]
         out["control_max_logit_gap"] = float(max((g.max() for g in cg),
@@ -365,13 +326,18 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
              patch: Optional[Callable] = None,
              config: Optional[dict] = None,
              traffic: Optional[dict] = None,
-             control: bool = False) -> dict:
+             control: bool = False, save: str = "",
+             keep: Optional[dict] = None) -> dict:
     """One run: the result line's object (see run.py).  ``config`` and
     ``traffic`` stand in for the cell's files, ``patch(engine)`` plants a
     fault (both for tests); ``control`` also reads the control's gap on
-    the same sample (for setting the limit)."""
+    the same sample (for setting the limit); ``save`` is a file to write
+    the trace to as :func:`chipbench.serve_trace.read_xspace` keeps it,
+    and ``keep`` a dict to copy the readers' context into (both for
+    ``trace_layers.py``)."""
     config = config or spec.load_config(cell["config"])
     traffic = traffic or spec.load_traffic(cell["traffic"])
+    arch = spec.arch_of(config)
     watch = CompileWatch()
     eng, sc = build(config, traffic, seed)
     _memory("after weights and bank")
@@ -401,7 +367,7 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
 
     reduced = None
     if trace and "dir" in tr:
-        reduced = _reduce_trace(tr)
+        reduced = _reduce_trace(tr, arch, save)
         shutil.rmtree(tr["dir"], ignore_errors=True)
     dev = jax.devices()[0]
     del eng, sc
@@ -417,8 +383,9 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
 
     ctx = {"records": recs, "window": (t0, window_end),
            "seconds": seconds, "setup_s": setup_s, "stats": stats,
-           "config": config, "traffic": traffic, "trace": reduced,
-           "span": (tr.get("t0"), tr.get("t1")),
+           "config": config, "arch": arch, "traffic": traffic,
+           "trace": reduced, "span": (tr.get("t0"), tr.get("t1")),
+           "counters": tr.get("counters"),
            "peak": work.peak(dev.device_kind) if dev.platform == "tpu"
            else None}
     kind = "per_layer" if trace else "end_to_end"
@@ -427,6 +394,8 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
         v = spec.load_reader(m["name"])(ctx)
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    if keep is not None:
+        keep.update(ctx)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": peak}
     result = {"correct": bool(correct), "attempted": len(recs),
@@ -451,14 +420,20 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
     return result
 
 
-def _reduce_trace(tr: dict) -> dict:
+def _reduce_trace(tr: dict, arch, save: str = "") -> dict:
     """Reduce the traced span, bounded by the ``bench:window`` host span
-    on the trace's own clock."""
-    path = next(Path(tr["dir"]).rglob("*.xplane.pb"))
-    raw = traces.read_xspace(str(path))
+    on the trace's own clock, with the architecture's kernels and scopes
+    (and write what was read to ``save``, where given)."""
+    raw = serve_trace.read_xspace(
+        str(next(Path(tr["dir"]).rglob("*.xplane.pb"))))
+    if save:
+        Path(save).parent.mkdir(parents=True, exist_ok=True)
+        traces.save(raw, save)
     win = next(h for h in raw["host"]
                if h[0] == traces.HOST_PREFIX + "window")
-    red = traces.reduce(raw, win[1], win[1] + win[2], KERNELS, PROGRAMS)
+    red = serve_trace.reduce(raw, win[1], win[1] + win[2], arch.KERNELS,
+                             PROGRAMS, arch.SCOPES)
     log(f"trace: busy {red['busy_s']:.4f} s of {red['window_s']:.4f} s; "
-        f"programs {red['programs']}; kernels {red['kernels']}")
+        f"programs {red['programs']}; kernels {red['kernels']}; "
+        f"idle by span {red['idle_by_span']}")
     return red

@@ -1,9 +1,13 @@
 """What the metric readers under ``metrics/`` share.
 
-A reader gets the run's context (token records, the window, the engine's
-``last_stats``, the reduced trace of a ``--trace 1`` run, the
-configuration, the traffic and the chip's peaks) and returns a number, or
-``None`` where it finds nothing to read.
+A reader gets the run's context and returns a number, or ``None`` where
+it finds nothing to read.  The context holds the token records
+(``records``), the window (``window``, ``seconds``, ``setup_s``), the
+session's ``stats``, the configuration and its architecture module
+(``config``, ``arch``), the ``traffic``, the chip's ``peak``, and in a
+``--trace 1`` run the traced span's ends (``span``), its reduced trace
+(``trace``, :func:`chipbench.serve_trace.reduce`) and the session's
+counters over it (``counters``).
 """
 from __future__ import annotations
 
@@ -51,7 +55,7 @@ def _span_work(ctx):
     t0, t1 = ctx["span"]
     if t0 is None or ctx["peak"] is None:
         return None
-    w = work.span_work(ctx["config"], ctx["records"], t0, t1,
+    w = work.span_work(ctx["arch"], ctx["config"], ctx["records"], t0, t1,
                        ctx["traffic"]["prefill_chunk"])
     return w, t1 - t0
 
@@ -67,22 +71,25 @@ def mfu(ctx) -> Optional[float]:
     return 100.0 * w["flops"] / (secs * ctx["peak"]["bf16_flops"])
 
 
-def attn_roofline(ctx) -> Optional[float]:
-    """Per cent: the least time the chip needs for the attention of the
-    tokens served in the traced span (the larger of their attention FLOPs
-    at peak and the K/V bytes of their contexts at peak bandwidth) over
-    the device time of the two paged-attention kernels in the span."""
+def roofline(ctx, kind: str) -> Optional[float]:
+    """Per cent: the least time the chip needs for the ``kind`` work of the
+    tokens served in the traced span (the larger of their ``<kind>_flops``
+    at peak and their ``<kind>_bytes`` at peak bandwidth) over the device
+    time in the span of the kernels that the architecture bounds by that
+    work."""
     sw = _span_work(ctx)
     tr = ctx["trace"]
     if sw is None or not tr:
         return None
-    secs = sum(k["seconds"] for k in tr["kernels"].values())
+    kernels = ctx["arch"].KERNELS
+    secs = sum(k["seconds"] for name, k in tr["kernels"].items()
+               if kernels[name]["work"] == kind)
     w = sw[0]
-    if secs <= 0 or w["attn_flops"] == 0:
+    if secs <= 0 or w[f"{kind}_flops"] == 0:
         return None
     pk = ctx["peak"]
-    least = max(w["attn_flops"] / pk["bf16_flops"],
-                w["attn_bytes"] / pk["hbm_bytes_per_s"])
+    least = max(w[f"{kind}_flops"] / pk["bf16_flops"],
+                w[f"{kind}_bytes"] / pk["hbm_bytes_per_s"])
     return 100.0 * least / secs
 
 
@@ -96,18 +103,27 @@ def program_ms(ctx, name: str) -> Optional[float]:
     return 1e3 * sum(each) / len(each)
 
 
+def decode_steps(ctx) -> Optional[float]:
+    """Decode steps in the traced span: the calls of the kernels that the
+    architecture marks ``"per_step"`` (called once a decode step in each
+    attention layer) over its attention layers."""
+    tr = ctx["trace"]
+    arch = ctx["arch"]
+    layers = arch.attention_layers(ctx["config"])
+    names = [n for n, k in arch.KERNELS.items() if k.get("per_step")]
+    if not tr or not names or layers <= 0:
+        return None
+    return sum(tr["kernels"].get(n, {}).get("count", 0)
+               for n in names) / layers
+
+
 def decode_step_ms(ctx) -> Optional[float]:
     """Device milliseconds of the decode program per decode step: its
-    time in the traced span over the span's paged-attention kernel calls
-    per layer."""
-    tr = ctx["trace"]
-    if not tr:
+    time in the traced span over :func:`decode_steps`."""
+    steps = decode_steps(ctx)
+    if not steps:
         return None
-    steps = tr["kernels"]["paged_attention"]["count"] \
-        / ctx["config"]["num_hidden_layers"]
-    if steps <= 0:
-        return None
-    return 1e3 * tr["programs"]["decode"]["seconds"] / steps
+    return 1e3 * ctx["trace"]["programs"]["decode"]["seconds"] / steps
 
 
 def queue_wait_p50_ms(ctx) -> Optional[float]:

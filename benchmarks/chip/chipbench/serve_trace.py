@@ -7,19 +7,22 @@ read:
 * host spans named ``serve:<phase>`` (``serve:step`` and the phases inside
   it, ``serve:intake`` and ``serve:deliver`` in the front end's pump), each
   with the session's step index as its ``step`` argument;
-* a ``jax.named_scope`` on the LoRA update (``lora``), attention
-  (``attn``) and the MLP (``mlp``), which reaches each device op's scope
-  path in the trace;
+* a ``jax.named_scope`` on each part of a layer (for a dense layer: the
+  LoRA update ``lora``, attention ``attn`` and the MLP ``mlp``; the
+  architecture module lists them as ``SCOPES``), which reaches each
+  device op's scope path in the trace;
 * cumulative counters, ``StreamSession.counters()``.
 
-:func:`read_xspace` keeps what :func:`chipbench.traces.read_xspace` keeps,
-each device op with its scope path as a fourth field, and the ``serve:``
-spans.  :func:`reduce` adds to :func:`chipbench.traces.reduce` the decode
-program's device time by scope and the idle time by the host span that
-was running, and names each long idle gap by the innermost ``serve:`` span
-in it.  :func:`occupancy`, :func:`lora_share` and :func:`kv_live_share`
-read a run's context as the metric readers do, with ``ctx["counters"]``
-the difference of two counter snapshots taken at the traced span's ends.
+:func:`read_xspace` keeps the device's ops (each with its scope path as a
+fourth field) and modules, the benchmark's ``bench:window`` span and
+the program's ``serve:`` spans.  :func:`reduce` adds to
+:func:`chipbench.traces.reduce` the decode program's device time by scope
+and the idle time by the host span that was running, and names each
+long idle gap by the innermost ``serve:`` span in it (the one reducer
+that names gaps).  :func:`occupancy`,
+:func:`lora_share` and :func:`kv_live_share` read a run's context as the
+metric readers do, with ``ctx["counters"]`` the difference of two
+counter snapshots taken at the traced span's ends.
 """
 from __future__ import annotations
 
@@ -29,18 +32,16 @@ from typing import Dict, List, Optional, Sequence
 from chipbench import traces
 
 SERVE_PREFIX = "serve:"
-# the program's named scopes, innermost first: the LoRA update runs inside
-# the projections of attention and of the MLP
-SCOPES = ("lora", "attn", "mlp")
 # the stat of a device op's event metadata that holds its scope path
 SCOPE_STAT = "tf_op"
-NO_SPAN = "no benchmark span"
+NO_SPAN = "no serve span"
 
 
-def scope_of(path: str) -> str:
-    """The innermost of ``SCOPES`` in an op's scope path, else ``other``."""
+def scope_of(path: str, scopes: Sequence[str]) -> str:
+    """The innermost of ``scopes`` (listed innermost first) in an op's
+    scope path, else ``other``."""
     parts = set(path.split("/"))
-    return next((s for s in SCOPES if s in parts), "other")
+    return next((s for s in scopes if s in parts), "other")
 
 
 def _varint(buf, i: int):
@@ -115,10 +116,12 @@ def op_scopes(path: str, device: str) -> Dict[str, str]:
 
 
 def read_xspace(path: str, device: str = "/device:TPU:0") -> dict:
-    """:func:`chipbench.traces.read_xspace`'s lists, with each device op
-    as ``[name, start_ns, dur_ns, scope_path]`` (:func:`op_scopes`), and
-    ``"serve"``: the program's spans as ``[name, start_ns, dur_ns,
-    thread, step]`` (``thread``: the host line's index in its plane)."""
+    """{"ops": [[name, start_ns, dur_ns, scope_path]], "modules": [[name,
+    start_ns, dur_ns]], "host": [...], "serve": [...]} of one device
+    (scope paths from :func:`op_scopes`), the benchmark's ``bench:`` host
+    span (``bench:window``), and the program's spans as ``[name,
+    start_ns, dur_ns, thread, step]`` (``thread``: the host line's index
+    in its plane)."""
     from jax.profiler import ProfileData
 
     scopes = op_scopes(path, device)
@@ -160,9 +163,9 @@ class SpanNamer:
     """Names an interval by the host span that was running in it: the
     innermost ``serve:`` span on the path of most overlap (the top-level
     span that overlaps it most, then its child that does, and so on), else
-    the ``bench:`` span that overlaps it most, else :data:`NO_SPAN`."""
+    :data:`NO_SPAN`."""
 
-    def __init__(self, serve: Sequence, bench: Sequence):
+    def __init__(self, serve: Sequence):
         order = sorted(range(len(serve)),
                        key=lambda i: (serve[i][1], -serve[i][2]))
         self.spans = [(serve[i][0], serve[i][1], serve[i][1] + serve[i][2],
@@ -179,9 +182,6 @@ class SpanNamer:
                 stack.pop()
             self.parent.append(stack[-1] if stack else None)
             stack.append(i)
-        self.bench = [(h[0][len(traces.HOST_PREFIX):], h[1], h[1] + h[2])
-                      for h in bench
-                      if h[0] != traces.HOST_PREFIX + "window"]
 
     def __call__(self, a: float, b: float) -> str:
         lo = bisect.bisect_left(self.starts, a - self.longest)
@@ -195,40 +195,34 @@ class SpanNamer:
             if not kids:
                 break
             cur = max(kids, key=lambda i: (over[i], -i))
-        if cur is not None:
-            return self.spans[cur][0]
-        best, name = 0.0, NO_SPAN
-        for n, s, e in self.bench:
-            ov = min(b, e) - max(a, s)
-            if ov > best:
-                best, name = ov, n
-        return name
+        return self.spans[cur][0] if cur is not None else NO_SPAN
 
 
 def decode_scopes(ops, modules, start_ns: float, end_ns: float,
-                  module: str) -> Dict[str, float]:
+                  module: str, scopes: Sequence[str]) -> Dict[str, float]:
     """Seconds of the device ``ops``' time in [start_ns, end_ns) inside
     executions of ``module``, by :func:`scope_of` (loops, which hold other
     ops, left out)."""
-    out = {s: 0.0 for s in SCOPES + ("other",)}
+    out = {s: 0.0 for s in (*scopes, "other")}
     for e in traces._in_module(ops, modules, module):
         if traces.op_name(e) == "%while":
             continue
         a, b = max(e[1], start_ns), min(e[1] + e[2], end_ns)
-        out[scope_of(e[3] if len(e) > 3 else "")] += (b - a) * 1e-9
+        out[scope_of(e[3] if len(e) > 3 else "", scopes)] += (b - a) * 1e-9
     return out
 
 
 def reduce(trace: dict, start_ns: float, end_ns: float,
            kernels: Dict[str, dict], programs: Dict[str, str],
-           top: int = 10) -> dict:
-    """:func:`chipbench.traces.reduce`, with ``idle_gaps`` named by
-    :class:`SpanNamer`, and two more entries: ``idle_by_span``, the idle
-    seconds in the span by the host span that was running (longest
-    first), and ``scopes``, the decode program's op seconds by scope."""
+           scopes: Sequence[str], top: int = 10) -> dict:
+    """:func:`chipbench.traces.reduce`, and three more entries:
+    ``idle_gaps``, the ``top`` longest idle gaps, each named by
+    :class:`SpanNamer`; ``idle_by_span``, the idle seconds in the span by
+    the host span that was running (longest first); and ``scopes``, the
+    decode program's op seconds by each of ``scopes``."""
     out = traces.reduce(trace, start_ns, end_ns, kernels, programs, top)
     ops = [e for e in trace["ops"] if e[1] < end_ns and e[1] + e[2] > start_ns]
-    name = SpanNamer(trace.get("serve", []), trace["host"])
+    name = SpanNamer(trace.get("serve", []))
     gaps = [(name(a, b), (b - a) * 1e-9)
             for a, b in idle_intervals(ops, start_ns, end_ns)]
     by: Dict[str, float] = {}
@@ -238,7 +232,7 @@ def reduce(trace: dict, start_ns: float, end_ns: float,
     out["idle_gaps"] = [list(g) for g in gaps[:top]]
     out["idle_by_span"] = dict(sorted(by.items(), key=lambda kv: -kv[1]))
     out["scopes"] = decode_scopes(ops, trace["modules"], start_ns, end_ns,
-                                  programs["decode"])
+                                  programs["decode"], scopes)
     return out
 
 
@@ -275,7 +269,7 @@ def lora_share(ctx) -> Optional[float]:
     tr = ctx.get("trace")
     if not tr or "scopes" not in tr:
         return None
-    total = sum(tr["scopes"].values())
-    if total <= 0 or tr["scopes"]["lora"] <= 0:
+    total, lora = sum(tr["scopes"].values()), tr["scopes"].get("lora", 0.0)
+    if total <= 0 or lora <= 0:
         return None
-    return 100.0 * tr["scopes"]["lora"] / total
+    return 100.0 * lora / total

@@ -1,11 +1,14 @@
 """Reduction of a profiler trace to device time, idle gaps and the time of
 programs and kernels.
 
-:func:`read_xspace` keeps what the reduction needs from an ``.xplane.pb``
-(the device's op and module lines, and the benchmark's own host spans,
-named ``bench:<what>``) as plain lists, which :func:`save` and
-:func:`load` write and read as JSON, so a small recorded trace can be
-checked without a chip.  :func:`reduce` then works on that alone.
+A trace is kept as plain lists (:func:`chipbench.serve_trace.read_xspace`
+reads them from an ``.xplane.pb``): the device's ops and modules as
+``[name, start_ns, dur_ns, ...]``, and the benchmark's own host span
+``bench:window``, which bounds the traced span.  :func:`save` and
+:func:`load` write and read them as JSON, so a small recorded trace can
+be checked without a chip.  :func:`reduce` works on that alone; the idle
+gaps are named by the program's spans, in
+:func:`chipbench.serve_trace.reduce`.
 
 Kernels are found by a key: the program (XLA module) whose name holds
 ``module`` and, inside it, the ops whose HLO instruction name starts with
@@ -21,27 +24,6 @@ from typing import Dict, List, Sequence, Tuple
 
 HOST_PREFIX = "bench:"
 OPS, MODULES = "XLA Ops", "XLA Modules"
-
-
-def read_xspace(path: str, device: str = "/device:TPU:0") -> dict:
-    """{"ops": [[name, start_ns, dur_ns]], "modules": [...], "host": [...]}
-    of one device and the benchmark's host spans."""
-    from jax.profiler import ProfileData
-
-    out: dict = {"ops": [], "modules": [], "host": []}
-    for plane in ProfileData.from_file(path).planes:
-        if plane.name == device:
-            for line in plane.lines:
-                key = {OPS: "ops", MODULES: "modules"}.get(line.name)
-                if key:
-                    out[key] += [[e.name, e.start_ns, e.duration_ns]
-                                 for e in line.events]
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                out["host"] += [[e.name, e.start_ns, e.duration_ns]
-                                for e in line.events
-                                if e.name.startswith(HOST_PREFIX)]
-    return out
 
 
 def save(trace: dict, path: str) -> None:
@@ -99,9 +81,8 @@ def _in_module(ops, modules, module: str):
 def reduce(trace: dict, start_ns: float, end_ns: float,
            kernels: Dict[str, dict], programs: Dict[str, str],
            top: int = 10) -> dict:
-    """Busy and idle time of the device in [start_ns, end_ns), the time of
-    each program and kernel, the ops that took most time, and the longest
-    idle gaps with the host span that overlaps each most.
+    """Busy time of the device in [start_ns, end_ns), the time of each
+    program and kernel, and the ops that took most time.
 
     ``kernels``: name -> {"module": ..., "op": ...}; ``programs``: name ->
     module-name fragment.  Times in seconds."""
@@ -147,21 +128,6 @@ def reduce(trace: dict, start_ns: float, end_ns: float,
                                      - max(e[1], start_ns)
                                      for e in evs) * 1e-9}
 
-    edges = [start_ns] + [x for iv in busy_ivs for x in iv] + [end_ns]
-    gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
-            if edges[i + 1] > edges[i]]
-    gaps.sort(key=lambda g: g[0] - g[1])
-    host = [(h[0][len(HOST_PREFIX):], h[1], h[1] + h[2])
-            for h in trace["host"] if h[0] != HOST_PREFIX + "window"]
-
-    def doing(a: float, b: float) -> str:
-        best, name = 0.0, "no benchmark span"
-        for n, s, e in host:
-            ov = min(b, e) - max(a, s)
-            if ov > best:
-                best, name = ov, n
-        return name
-
     return {
         "window_s": (end_ns - start_ns) * 1e-9,
         "busy_s": busy * 1e-9,
@@ -169,5 +135,4 @@ def reduce(trace: dict, start_ns: float, end_ns: float,
         "kernels": kern,
         "device_ops": [[n, s * 1e-9] for n, s in
                        sorted(per_op.items(), key=lambda kv: -kv[1])[:top]],
-        "idle_gaps": [[doing(a, b), (b - a) * 1e-9] for a, b in gaps[:top]],
     }

@@ -1,6 +1,6 @@
-"""Roofline share of the two paged-attention kernels (closed-loop generation cells)."""
+"""Roofline share of the attention kernels (closed-loop generation cells)."""
 from chipbench import readers
 
 
 def read(ctx):
-    return readers.attn_roofline(ctx)
+    return readers.roofline(ctx, "attn")

@@ -1,6 +1,6 @@
-"""Roofline share of the two paged-attention kernels (open-loop long-prompt cells)."""
+"""Roofline share of the attention kernels (open-loop long-prompt cells)."""
 from chipbench import readers
 
 
 def read(ctx):
-    return readers.attn_roofline(ctx)
+    return readers.roofline(ctx, "attn")

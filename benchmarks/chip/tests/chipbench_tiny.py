@@ -1,8 +1,9 @@
 """A tiny configuration and traffic that a CPU run can hold."""
 
 TINY_CONFIG = {
-    "name": "tiny", "hidden_size": 64, "intermediate_size": 128,
-    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "name": "tiny", "arch": "dense", "hidden_size": 64,
+    "intermediate_size": 128, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16,
     "num_hidden_layers": 2, "vocab_size": 256,
     "max_position_embeddings": 128, "rope_theta": 10000.0,
     "tie_word_embeddings": False, "norm": "rmsnorm", "norm_eps": 1e-6,

@@ -12,21 +12,22 @@ import numpy as np
 import pytest
 from chipbench_tiny import TINY_CONFIG, TINY_TIED
 
-from chipbench import harness
+from chipbench import harness, spec
 from chipbench import reference as R
-from chipbench import weights as W
+
+DENSE = spec.load_arch("dense")
 
 
 def test_a_layer_made_alone_equals_its_stacked_slice():
-    d = W.dims(TINY_CONFIG)
-    full = W.init_params(d, 2**32 + 3)["blocks"]["b0"]
-    one = W.layer_params(d, 2**32 + 3, 1)
+    full = DENSE.init_params(TINY_CONFIG, 2**32 + 3)["blocks"]["b0"]
+    one = DENSE.layer_params(TINY_CONFIG, 2**32 + 3, 1)
     for a, b in zip(jax.tree.leaves(one),
                     jax.tree.leaves(jax.tree.map(lambda x: x[1], full))):
         assert np.array_equal(np.asarray(a), np.asarray(b))
-    emb, head, _ = W.embed_head(d, 2**32 + 3)
-    assert np.array_equal(np.asarray(emb),
-                          np.asarray(W.init_params(d, 2**32 + 3)["embed"]))
+    emb, head, _ = DENSE.embed_head(TINY_CONFIG, 2**32 + 3)
+    assert np.array_equal(
+        np.asarray(emb),
+        np.asarray(DENSE.init_params(TINY_CONFIG, 2**32 + 3)["embed"]))
 
 
 @pytest.mark.parametrize("config", [TINY_CONFIG, TINY_TIED],
@@ -38,11 +39,10 @@ def test_reference_matches_program_forward(config):
 
     seed, n_clients, S = 11, 3, 20
     cfg = harness.program_config(config).with_overrides(dtype="float32")
-    d = W.dims(config)
-    params = W.init_params(d, seed)
+    params = DENSE.init_params(config, seed)
     reg = AdapterRegistry(cfg, capacity=n_clients)
     for c in range(n_clients):
-        reg.register_dual(f"c{c}", *W.client_adapters(d, seed, c))
+        reg.register_dual(f"c{c}", *DENSE.client_adapters(config, seed, c))
     rng = np.random.default_rng(0)
     toks = rng.integers(1, config["vocab_size"], (n_clients, S)).astype(
         np.int32)
@@ -51,7 +51,7 @@ def test_reference_matches_program_forward(config):
         got, _ = get_model(cfg).forward(
             params, {"tokens": jnp.asarray(toks)}, adapters=reg.bank(),
             lora_scale=lora_scale(cfg), adapter_ids=ids)
-    ref = R.Reference(config, seed)
+    ref = DENSE.Reference(config, seed)
     hs = ref.hidden([(c, toks[c]) for c in range(n_clients)], 32)
     head = ref.head()
     for c in range(n_clients):
@@ -64,8 +64,8 @@ def test_control_departs_from_reference():
     """float8 matmul inputs move the logits far more than float32
     rounding: the control is a real step down in precision."""
     toks = np.arange(1, 30, dtype=np.int32)
-    ref = R.Reference(TINY_CONFIG, 5)
-    ctl = R.Reference(TINY_CONFIG, 5, quant=True)
+    ref = DENSE.Reference(TINY_CONFIG, 5)
+    ctl = DENSE.Reference(TINY_CONFIG, 5, quant=True)
     h = ref.hidden([(0, toks)], 32)[0]
     hc = ctl.hidden([(0, toks)], 32)[0]
     head = ref.head()

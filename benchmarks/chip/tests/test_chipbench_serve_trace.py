@@ -18,6 +18,8 @@ import pytest  # noqa: E402
 from chipbench import harness, serve_trace, spec, traces  # noqa: E402
 from chipbench_tiny import TINY_CONFIG, TINY_TRAFFIC  # noqa: E402
 
+DENSE = spec.load_arch("dense")
+
 TESTDATA = Path(__file__).resolve().parents[1] / "testdata"
 DECODE = "jit(_decode_chunk_impl)/while/body"
 
@@ -27,14 +29,17 @@ def _op(name, start, dur, scope=""):
 
 
 def test_scope_of_takes_the_innermost_named_scope():
-    assert serve_trace.scope_of(f"{DECODE}/attn/lora/dot_general") == "lora"
-    assert serve_trace.scope_of(f"{DECODE}/mlp/lora/gather") == "lora"
-    assert serve_trace.scope_of(f"{DECODE}/attn/dot_general") == "attn"
-    assert serve_trace.scope_of(f"{DECODE}/mlp/mul") == "mlp"
-    assert serve_trace.scope_of(f"{DECODE}/argmax") == "other"
-    assert serve_trace.scope_of("") == "other"
+    def scope(path):
+        return serve_trace.scope_of(path, DENSE.SCOPES)
+
+    assert scope(f"{DECODE}/attn/lora/dot_general") == "lora"
+    assert scope(f"{DECODE}/mlp/lora/gather") == "lora"
+    assert scope(f"{DECODE}/attn/dot_general") == "attn"
+    assert scope(f"{DECODE}/mlp/mul") == "mlp"
+    assert scope(f"{DECODE}/argmax") == "other"
+    assert scope("") == "other"
     # a scope name must be a whole path component
-    assert serve_trace.scope_of(f"{DECODE}/attn_out/lorax") == "other"
+    assert scope(f"{DECODE}/attn_out/lorax") == "other"
 
 
 def _varint(n):
@@ -107,21 +112,21 @@ def test_gaps_go_to_the_innermost_serve_span():
                     ["serve:plan", 16, 6, "t1", 1],
                     ["serve:deliver", 31, 3, "t2", 1],
                     ["serve:observe", 32, 8, "t1", 1]]}
-    r = serve_trace.reduce(tr, 0, 80, {}, harness.PROGRAMS)
+    r = serve_trace.reduce(tr, 0, 80, {}, harness.PROGRAMS, DENSE.SCOPES)
     # gaps: [10, 20) in step > flush > sync (4) and plan (4): the first
     # child overlapping most wins, then its child; [30, 40) overlaps
     # observe (8) more than deliver (3); [45, 60) overlaps step (3) and
-    # the bench span admit (5): a serve: span comes first; [70, 80) none
+    # the bench span admit (5), which names nothing; [70, 80) none
     assert r["idle_gaps"] == [
         ["serve:step", pytest.approx(15e-9)],
         ["serve:sync", pytest.approx(10e-9)],
         ["serve:observe", pytest.approx(10e-9)],
-        ["no benchmark span", pytest.approx(10e-9)]]
+        [serve_trace.NO_SPAN, pytest.approx(10e-9)]]
     assert r["idle_by_span"] == {
         "serve:step": pytest.approx(15e-9),
         "serve:sync": pytest.approx(10e-9),
         "serve:observe": pytest.approx(10e-9),
-        "no benchmark span": pytest.approx(10e-9)}
+        serve_trace.NO_SPAN: pytest.approx(10e-9)}
     # the decode program's op time by scope; the loop and the prefill op
     # are not counted
     assert r["scopes"] == {"lora": pytest.approx(10e-9),
@@ -130,19 +135,22 @@ def test_gaps_go_to_the_innermost_serve_span():
     assert serve_trace.lora_share({"trace": r}) == pytest.approx(40.0)
 
 
-def test_without_serve_spans_gaps_fall_back_to_bench_spans():
-    name = serve_trace.SpanNamer([], [["bench:window", 0, 100],
-                                      ["bench:session.step", 10, 10],
-                                      ["bench:scheduler.admit", 18, 10]])
-    assert name(12, 20) == "session.step"
-    assert name(19, 30) == "scheduler.admit"
-    assert name(50, 60) == serve_trace.NO_SPAN
+def test_bench_spans_name_no_gap():
+    tr = {"ops": [_op("fusion.1", 0, 10, DECODE), _op("fusion.2", 30, 10,
+                                                     DECODE)],
+          "modules": [["jit__decode_chunk_impl(1)", 0, 40]],
+          "host": [["bench:window", 0, 50], ["bench:session.step", 10, 10]],
+          "serve": [["serve:step", 40, 10, "t1", 1]]}
+    r = serve_trace.reduce(tr, 0, 50, {}, harness.PROGRAMS, DENSE.SCOPES)
+    assert r["idle_gaps"] == [[serve_trace.NO_SPAN, pytest.approx(20e-9)],
+                              ["serve:step", pytest.approx(10e-9)]]
+    assert serve_trace.SpanNamer([])(10, 20) == serve_trace.NO_SPAN
 
 
 def test_spans_of_other_threads_do_not_nest():
     name = serve_trace.SpanNamer(
         [["serve:step", 0, 100, "a", 1], ["serve:plan", 0, 30, "a", 1],
-         ["serve:intake", 0, 100, "b", 1]], [])
+         ["serve:intake", 0, 100, "b", 1]])
     # intake (thread b) is no child of step: both are top-level, and the
     # first of equal overlaps is taken before descending
     assert name(5, 20) == "serve:plan"
@@ -153,13 +161,17 @@ def test_recorded_slice_keeps_every_other_output():
     tr = traces.load(str(TESTDATA / "olmo_trace_slice.json.gz"))
     win = [h for h in tr["host"] if h[0] == "bench:window"][0]
     lo, hi = win[1], win[1] + win[2]
-    old = traces.reduce(tr, lo, hi, harness.KERNELS, harness.PROGRAMS)
-    new = serve_trace.reduce(tr, lo, hi, harness.KERNELS, harness.PROGRAMS)
+    old = traces.reduce(tr, lo, hi, DENSE.KERNELS, harness.PROGRAMS)
+    new = serve_trace.reduce(tr, lo, hi, DENSE.KERNELS, harness.PROGRAMS,
+                             DENSE.SCOPES)
     for key in old:
-        if key != "idle_gaps":
-            assert new[key] == old[key], key
-    # without serve: spans the gaps keep their bench: names and lengths
-    assert new["idle_gaps"] == old["idle_gaps"]
+        assert new[key] == old[key], key
+    # without serve: spans no gap has a name; the ten longest are kept
+    gaps = sorted(b - a for a, b in serve_trace.idle_intervals(
+        [e for e in tr["ops"] if e[1] < hi and e[1] + e[2] > lo], lo, hi))
+    assert new["idle_gaps"] == [
+        [serve_trace.NO_SPAN, pytest.approx(g * 1e-9)]
+        for g in gaps[::-1][:10]]
     assert sum(new["idle_by_span"].values()) == pytest.approx(
         new["window_s"] - new["busy_s"])
     # ops recorded without a scope count as "other"
@@ -173,7 +185,8 @@ def test_recorded_chip_slice_with_spans_and_scopes():
     tr = traces.load(str(TESTDATA / "olmo_serve_slice.json.gz"))
     win = [h for h in tr["host"] if h[0] == "bench:window"][0]
     lo, hi = win[1], win[1] + win[2]
-    r = serve_trace.reduce(tr, lo, hi, harness.KERNELS, harness.PROGRAMS)
+    r = serve_trace.reduce(tr, lo, hi, DENSE.KERNELS, harness.PROGRAMS,
+                           DENSE.SCOPES)
     # the scope path reached the ops as the chip wrote it: every named
     # scope holds time, and the decode kernel runs under attn
     sc = r["scopes"]
@@ -181,7 +194,8 @@ def test_recorded_chip_slice_with_spans_and_scopes():
     kernel = r["kernels"]["paged_attention"]
     assert kernel["count"] == 2
     assert sc["attn"] >= kernel["seconds"]
-    assert all(serve_trace.scope_of(e[3]) == "attn" for e in tr["ops"]
+    assert all(serve_trace.scope_of(e[3], DENSE.SCOPES) == "attn"
+               for e in tr["ops"]
                if e[0].startswith("%paged_attention."))
     # the scopes split the decode program's op time, loops left out
     ops = [e for e in tr["ops"] if e[1] < hi and e[1] + e[2] > lo]
@@ -273,7 +287,8 @@ def test_profiled_server_spans_nest_in_steps(tmp_path):
 def test_traced_run_reads_the_counters():
     """A whole traced run at a tiny size, closed loop, more users than
     slots: the counters read over the traced span give occupancy and the
-    live share of the decode grid; the CPU trace holds no device op, so
+    live share of the decode grid, in the cell's per-layer metrics and in
+    ``trace_layers.py``'s additions; the CPU trace holds no device op, so
     the LoRA share reads nothing."""
     import trace_layers
 
@@ -283,9 +298,9 @@ def test_traced_run_reads_the_counters():
                                "max": 80})
     config = dict(TINY_CONFIG, serving={"block_size": 8,
                                         "kv_pool_blocks": 65})
-    cell = {"name": "tiny.closed", "config": "tiny", "traffic": "tiny",
-            "chips": 1, "why": "test"}
-    r = trace_layers.run_traced(spec.load_benchmark(), cell, 2**31 + 5, 2.0,
+    bench = spec.load_benchmark()
+    cell = spec.find_cell(bench, "yi-6b-l16.gen-closed")
+    r = trace_layers.run_traced(bench, cell, 2**31 + 5, 2.0,
                                 time.perf_counter(), config=config,
                                 traffic=traffic)
     assert r["correct"]
@@ -295,6 +310,10 @@ def test_traced_run_reads_the_counters():
     assert 0 < s["occupancy"] <= 100
     assert 0 < s["kv_live_share"] <= 100
     assert s["lora_share"] is None
-    assert "setup_s" in r["metrics"]
+    m = r["metrics"]
+    assert m["occupancy.tok"]["value"] == s["occupancy"]
+    assert m["kv_live_share.tok"]["value"] == s["kv_live_share"]
+    assert "lora_share.tok" not in m
+    assert "setup_s" in m and "out_tok_s" in m
     # the harness is left as it was
     assert harness._trace_span.__module__ == "chipbench.harness"

@@ -10,7 +10,6 @@ import pytest
 
 from chipbench import harness, spec
 from chipbench import traffic as TR
-from chipbench import weights as W
 
 CONFIGS = sorted(p.stem for p in (spec.BENCH_DIR / "configs").glob("*.json"))
 TRAFFIC = sorted(p.stem for p in (spec.BENCH_DIR / "traffic").glob("*.json"))
@@ -25,8 +24,18 @@ def test_config_file_maps_to_the_program(name):
             cfg.d_ff, cfg.vocab_size) == (
         c["num_hidden_layers"], c["hidden_size"], c["num_attention_heads"],
         c["num_key_value_heads"], c["intermediate_size"], c["vocab_size"])
-    assert W.dims(c).layers == c["num_hidden_layers"]
+    arch = spec.arch_of(c)
+    assert 0 < arch.attention_layers(c) <= c["num_hidden_layers"]
+    assert set(arch.SCOPES) >= {"lora"}
+    assert all(k["work"] for k in arch.KERNELS.values())
     assert spec.as_run(c, "norm_eps") == 1e-6
+
+
+def test_a_file_without_an_arch_is_an_error():
+    c = spec.load_config("olmo-1b")
+    del c["arch"]
+    with pytest.raises(KeyError, match="names no 'arch'"):
+        harness.program_config(c)
 
 
 def test_a_key_the_source_leaves_out_takes_its_default():

@@ -9,7 +9,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import pytest
 
-from chipbench import harness, traces
+from chipbench import harness, serve_trace, spec, traces
 
 SLICE = Path(__file__).resolve().parents[1] / "testdata" / \
     "olmo_trace_slice.json.gz"
@@ -24,9 +24,7 @@ def test_busy_gaps_and_attribution_by_hand():
                   _op("paged_attention.3", 20, 10),
                   _op("while.4", 0, 30)],
           "modules": [["jit__decode_chunk_impl(1)", 0, 30]],
-          "host": [["bench:session.step", 12, 6],
-                   ["bench:scheduler.admit", 31, 2],
-                   ["bench:session.step", 30, 9]]}
+          "host": []}
     r = traces.reduce(tr, 0, 40, {"attn": {"module": "_decode_chunk_impl",
                                            "op": "%paged_attention."}},
                       {"decode": "_decode_chunk_impl"})
@@ -39,7 +37,9 @@ def test_busy_gaps_and_attribution_by_hand():
     assert [n for n, _ in r["device_ops"]] == [
         "_decode_chunk_impl/%fusion", "_decode_chunk_impl/%paged_attention"]
     assert r["device_ops"][0][1] == pytest.approx(20e-9)
-    assert r["idle_gaps"] == [["session.step", pytest.approx(10e-9)]]
+    # the one idle gap; naming it is serve_trace.reduce's
+    assert serve_trace.idle_intervals(tr["ops"], 0, 40) == [(30, 40)]
+    assert "idle_gaps" not in r
 
 
 def test_clipping_to_the_span():
@@ -68,7 +68,8 @@ def test_recorded_slice():
     tr = traces.load(str(SLICE))
     win = [h for h in tr["host"] if h[0] == "bench:window"][0]
     lo, hi = win[1], win[1] + win[2]
-    r = traces.reduce(tr, lo, hi, harness.KERNELS, harness.PROGRAMS)
+    r = traces.reduce(tr, lo, hi, spec.load_arch("dense").KERNELS,
+                      harness.PROGRAMS)
     assert r["window_s"] == pytest.approx(0.075)
     assert r["busy_s"] == pytest.approx(_busy_by_counting(tr["ops"], lo, hi),
                                         rel=2e-3)
@@ -85,5 +86,7 @@ def test_recorded_slice():
     assert r["programs"]["prefill"]["count"] == 0
     assert r["programs"]["decode"]["count"] == 0
     assert r["device_ops"][0][0] == "_decode_chunk_impl/%paged_attention"
-    assert r["idle_gaps"][0][0] == "session.step"
-    assert len(r["idle_gaps"]) == 10
+    gaps = serve_trace.idle_intervals(tr["ops"], lo, hi)
+    assert len(gaps) >= 10
+    assert sum(b - a for a, b in gaps) * 1e-9 == pytest.approx(
+        r["window_s"] - r["busy_s"])
