@@ -59,8 +59,17 @@ class ModelConfig:
 
     # Norm / activation flavour
     norm_type: str = "rmsnorm"       # rmsnorm | layernorm | nonparametric
+    norm_eps: float = 1e-6
     mlp_type: str = "swiglu"         # swiglu | geglu | gelu
     tie_embeddings: bool = False
+
+    # Published scalars (Granite's muP-style multipliers).  The defaults are
+    # the program's own rules: 0 embedding multiplier -> sqrt(d_model) for
+    # tied dense models, else 1; 0 attention multiplier -> head_dim ** -0.5.
+    embedding_multiplier: float = 0.0
+    residual_multiplier: float = 1.0     # scales each sublayer's output
+    attention_multiplier: float = 0.0    # the softmax scale
+    logits_scaling: float = 1.0          # logits are divided by this
 
     # MoE
     n_experts: int = 0
@@ -76,6 +85,7 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_n_groups: int = 1
     ssm_chunk: int = 128
+    ssm_conv_bias: bool = False
 
     # Encoder-decoder (whisper): encoder is attn+mlp, full attention,
     # learned positions, consumes stubbed frame embeddings.
@@ -129,6 +139,13 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def resolved_embedding_multiplier(self) -> float:
+        if self.embedding_multiplier:
+            return self.embedding_multiplier
+        tied_dense = self.family == "dense" and self.tie_embeddings
+        return self.d_model ** 0.5 if tied_dense else 1.0
+
+    @property
     def n_periods(self) -> int:
         return self.n_layers // len(self.layer_pattern)
 
@@ -168,7 +185,8 @@ class ModelConfig:
             "mamba": (d * (2 * self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_d_state
                            + self.ssm_n_heads)
                       + self.ssm_d_inner * d
-                      + self.ssm_d_conv * (self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_d_state)
+                      + (self.ssm_d_conv + self.ssm_conv_bias)
+                      * (self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_d_state)
                       + 2 * self.ssm_n_heads),
             "mlp": (3 if self.mlp_type in ("swiglu", "geglu") else 2) * d * ff,
             "moe": self.n_experts * (3 if self.mlp_type in ("swiglu", "geglu") else 2)

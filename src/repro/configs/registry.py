@@ -18,9 +18,13 @@ _MODULES = {
     "kimi-k2-1t-a32b": "repro.configs.kimi_k2_1t_a32b",
     "jamba-v0.1-52b": "repro.configs.jamba_v0p1_52b",
     "llama2-7b": "repro.configs.llama2_7b",  # the paper's own backbone
+    "granite-4.0-h-micro": "repro.configs.granite_4p0_h_micro",  # chip bench
 }
 
-ASSIGNED_ARCHS: List[str] = [k for k in _MODULES if k != "llama2-7b"]
+# the assignment's architectures: all but the paper's backbone and the
+# chip benchmark's hybrid
+ASSIGNED_ARCHS: List[str] = [k for k in _MODULES
+                             if k not in ("llama2-7b", "granite-4.0-h-micro")]
 ALL_ARCHS: List[str] = list(_MODULES)
 
 

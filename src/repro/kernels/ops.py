@@ -146,18 +146,53 @@ def batched_lora_dense(x: jnp.ndarray, w: jnp.ndarray,
     return y[:M, :N].reshape(*lead, N)
 
 
+def _slots(H: int, kv_rows: int, f: int, dtype) -> jnp.ndarray:
+    """(H, f) one-hot of each query head's slot in a packed pool row
+    (``layers.kv_pack``): query head i reads KV head g = i // (H / Kv),
+    which sits in slot g % f of row g // f."""
+    g = jnp.arange(H) // (H // (kv_rows * f))
+    return jax.nn.one_hot(g % f, f, dtype=dtype)
+
+
+def _pack_queries(q: jnp.ndarray, kv_rows: int, f: int) -> jnp.ndarray:
+    """Queries (..., H, hd) for a pool of ``kv_rows`` rows of ``f`` heads:
+    each in its head's slot of a zeroed ``f * hd``-lane row, so its
+    product with a pool row is its product with its own KV head, and the
+    kernels (H / kv_rows query heads to a pool row) pair it with that
+    head's row.  ``f == 1`` leaves q as it is."""
+    if f == 1:
+        return q
+    slots = _slots(q.shape[-2], kv_rows, f, q.dtype)
+    return (slots[:, :, None] * q[..., None, :]).reshape(
+        q.shape[:-1] + (f * q.shape[-1],))
+
+
+def _unpack_heads(o: jnp.ndarray, kv_rows: int, f: int) -> jnp.ndarray:
+    """The kernels' output rows (..., H, f * hd) -> (..., H, hd): each
+    query head's own slot (see :func:`_pack_queries`)."""
+    if f == 1:
+        return o
+    slots = _slots(o.shape[-2], kv_rows, f, o.dtype)
+    o = o.reshape(o.shape[:-1] + (f, o.shape[-1] // f))
+    return jnp.sum(o * slots[:, :, None], axis=-2)
+
+
 def paged_gqa_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                         v_pool: jnp.ndarray, block_tables: jnp.ndarray,
                         lengths: jnp.ndarray, *,
                         k_scale: Optional[jnp.ndarray] = None,
-                        v_scale: Optional[jnp.ndarray] = None
-                        ) -> jnp.ndarray:
+                        v_scale: Optional[jnp.ndarray] = None,
+                        scale: Optional[float] = None) -> jnp.ndarray:
     """Model-layout adapter for the paged decode kernel.
 
     q: (B, 1, H, hd) (or (B, H, hd)) as produced by the serving decode step;
-    k_pool/v_pool: (NB, bs, Kv, hd). Pads head_dim to 128 lanes (zero key
-    lanes leave q·k unchanged; zero value lanes are sliced away) and keeps
-    the block-table gather inside the kernel. Returns q's shape.
+    k_pool/v_pool: (NB, bs, Kv, hd), or packed (NB, bs, Kv // f, f * hd)
+    (``layers.kv_pack``), which the kernel reads in place with queries
+    placed in their head's slot.  An unpacked head_dim under 128 is padded
+    to 128 lanes (a copy of the pool: zero key lanes leave q·k unchanged;
+    zero value lanes are sliced away).  The block-table gather stays
+    inside the kernel.  ``scale`` is the softmax scale (default
+    ``hd ** -0.5``).  Returns q's shape.
 
     With int8 pools pass ``k_scale``/``v_scale`` ((NB, bs, Kv) fp32) —
     they carry no head-dim axis so the lane padding leaves them alone and
@@ -171,7 +206,9 @@ def paged_gqa_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     if squeeze:
         q = q[:, 0]
     hd = q.shape[-1]
-    scale = hd ** -0.5                       # scale from the *unpadded* head
+    scale = scale if scale is not None else hd ** -0.5   # the unpadded head
+    rows, f = k_pool.shape[2], k_pool.shape[3] // hd
+    q = _pack_queries(q, rows, f)
     qp, _ = _pad_to(q, 2, 128)
     kp, _ = _pad_to(k_pool, 3, 128)
     vp, _ = _pad_to(v_pool, 3, 128)
@@ -179,7 +216,8 @@ def paged_gqa_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         lambda q, bt, ln, kp, vp, ks, vs: paged_attention(
             q, kp, vp, bt, ln, k_scale=ks, v_scale=vs, scale=scale),
         (qp, block_tables.astype(jnp.int32), lengths.astype(jnp.int32)),
-        (kp, vp, k_scale, v_scale))[..., :hd]
+        (kp, vp, k_scale, v_scale))[..., :q.shape[-1]]
+    o = _unpack_heads(o, rows, f)
     return o[:, None] if squeeze else o
 
 
@@ -190,16 +228,19 @@ def paged_prefill_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                                 lengths: jnp.ndarray,
                                 n_new: jnp.ndarray, *,
                                 k_scale: Optional[jnp.ndarray] = None,
-                                v_scale: Optional[jnp.ndarray] = None):
+                                v_scale: Optional[jnp.ndarray] = None,
+                                scale: Optional[float] = None):
     """Model-layout adapter for the chunked paged-prefill kernel.
 
     q/k_new/v_new: (B, T, H|Kv, hd) — a whole prompt chunk per serving slot,
-    as produced by the serving prefill step; k_pool/v_pool: (NB, bs, Kv, hd).
+    as produced by the serving prefill step; k_pool/v_pool: (NB, bs, Kv, hd)
+    or packed (see :func:`paged_gqa_attention`).
     Scatters the chunk's K/V into each row's block-table slots (positions
     ``lengths[b] .. lengths[b] + n_new[b] - 1``; ragged tails with
     ``t >= n_new[b]`` land in scratch block 0), then runs the Pallas kernel
     over the updated pools — the O(T) scatter is materialised, the
-    O(context) gather never is.  Pads head_dim to 128 lanes.
+    O(context) gather never is.  Pads an unpacked head_dim to 128 lanes.
+    ``scale`` is the softmax scale (default ``hd ** -0.5``).
 
     Returns (out (B, T, H, hd), new_k_pool, new_v_pool).  With int8 pools
     pass ``k_scale``/``v_scale`` ((NB, bs, Kv) fp32): the chunk quantizes
@@ -219,15 +260,18 @@ def paged_prefill_gqa_attention(q: jnp.ndarray, k_new: jnp.ndarray,
                                n_new.astype(jnp.int32))
         ks = vs = None
 
-    scale = hd ** -0.5                       # scale from the *unpadded* head
-    qp, _ = _pad_to(q, 3, 128)
+    scale = scale if scale is not None else hd ** -0.5   # the unpadded head
+    rows, f = kp.shape[2], kp.shape[3] // hd
+    qf = _pack_queries(q, rows, f)
+    qp, _ = _pad_to(qf, 3, 128)
     kpp, _ = _pad_to(kp, 3, 128)
     vpp, _ = _pad_to(vp, 3, 128)
     o = _per_data_shard(
         lambda q, bt, ln, kp, vp, ks, vs: paged_prefill_attention(
             q, kp, vp, bt, ln, k_scale=ks, v_scale=vs, scale=scale),
         (qp, block_tables.astype(jnp.int32), lengths.astype(jnp.int32)),
-        (kpp, vpp, ks, vs))[..., :hd]
+        (kpp, vpp, ks, vs))[..., :qf.shape[-1]]
+    o = _unpack_heads(o, rows, f)
     if quantized:
         return o, kp, vp, ks, vs
     return o, kp, vp

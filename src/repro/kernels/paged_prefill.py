@@ -64,7 +64,8 @@ def paged_scatter(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
                   n_new: jnp.ndarray | None = None):
     """Scatter S new K/V tokens per row into the shared paged pools.
 
-    k/v: (B, S, Kv, hd); token t of row b lands at
+    k/v: (B, S, Kv, hd), into pools of (NB, bs, Kv, hd) or packed
+    (NB, bs, Kv // f, f * hd) (``layers.kv_pack``); token t of row b lands at
     ``pool[table[b, (lengths[b]+t) // bs], (lengths[b]+t) % bs]``.  With
     ``n_new`` (B,), rows ``t >= n_new[b]`` (ragged chunk tails / inactive
     slots) are redirected to scratch block 0 — this is the ONE place the
@@ -83,8 +84,9 @@ def paged_scatter(k_pool: jnp.ndarray, v_pool: jnp.ndarray,
     B, S = k.shape[0], k.shape[1]
     blk, off = _scatter_coords(B, S, k_pool.shape[1], block_tables,
                                lengths, n_new)
-    return (k_pool.at[blk, off].set(k.astype(k_pool.dtype)),
-            v_pool.at[blk, off].set(v.astype(v_pool.dtype)))
+    row = (B, S) + k_pool.shape[2:]          # packed pools: heads side by side
+    return (k_pool.at[blk, off].set(k.reshape(row).astype(k_pool.dtype)),
+            v_pool.at[blk, off].set(v.reshape(row).astype(v_pool.dtype)))
 
 
 def paged_scatter_quant(k_pool: jnp.ndarray, v_pool: jnp.ndarray,

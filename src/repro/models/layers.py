@@ -10,8 +10,9 @@ Conventions
   ``y = x@W + (alpha/r) * (x@A)@B`` with the base weight frozen.
 * All matmuls accumulate in fp32 (``preferred_element_type``) and cast back.
 * The LoRA update, attention and the MLP run under ``jax.named_scope``s
-  ``lora``, ``attn`` and ``mlp``: op metadata only, so a profiler trace can
-  say which layer each device op belongs to.
+  ``lora``, ``attn`` and ``mlp`` (a Mamba-2 mixer's core under ``ssm``,
+  ``models/mamba2.py``): op metadata only, so a profiler trace can say
+  which layer each device op belongs to.
 """
 from __future__ import annotations
 
@@ -286,7 +287,7 @@ def _sdpa(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, cfg,
     of materialising the (B, Sk, H, hd) repeated K/V."""
     B, Sq, H, hd = q.shape
     Kv = k.shape[2]
-    scale = hd ** -0.5
+    scale = cfg.attention_multiplier or hd ** -0.5
     sm_dtype = dt(getattr(cfg, "attn_softmax_dtype", "float32"))
     grouped = getattr(cfg, "attn_impl", "repeat") == "grouped" and H != Kv
 
@@ -341,6 +342,7 @@ def _paged_attention_pallas(params, q, k, v, x, cfg, kv_cache, block_tables,
             "paged_backend='pallas' supports full attention only (no "
             "sliding window / logit softcap); use paged_backend='jnp'")
     quant = "k_scale" in kv_cache             # int8 pools carry scale leaves
+    scale = cfg.attention_multiplier or None  # None: head_dim ** -0.5
     if n_new is None and S == 1:
         if quant:
             kp, vp, ks, vs = paged_scatter_quant(
@@ -348,12 +350,12 @@ def _paged_attention_pallas(params, q, k, v, x, cfg, kv_cache, block_tables,
                 kv_cache["v_scale"], k, v, block_tables, lengths, None)
             o = kernel_ops.paged_gqa_attention(
                 q, kp, vp, block_tables, lengths + 1,
-                k_scale=ks, v_scale=vs)
+                k_scale=ks, v_scale=vs, scale=scale)
         else:
             kp, vp = paged_scatter(kv_cache["k_pool"], kv_cache["v_pool"],
                                    k, v, block_tables, lengths, None)
             o = kernel_ops.paged_gqa_attention(
-                q, kp, vp, block_tables, lengths + 1)
+                q, kp, vp, block_tables, lengths + 1, scale=scale)
     else:
         nn = (n_new if n_new is not None
               else jnp.full((B,), S, dtype=jnp.int32))
@@ -361,11 +363,11 @@ def _paged_attention_pallas(params, q, k, v, x, cfg, kv_cache, block_tables,
             o, kp, vp, ks, vs = kernel_ops.paged_prefill_gqa_attention(
                 q, k, v, kv_cache["k_pool"], kv_cache["v_pool"], block_tables,
                 lengths, nn, k_scale=kv_cache["k_scale"],
-                v_scale=kv_cache["v_scale"])
+                v_scale=kv_cache["v_scale"], scale=scale)
         else:
             o, kp, vp = kernel_ops.paged_prefill_gqa_attention(
                 q, k, v, kv_cache["k_pool"], kv_cache["v_pool"], block_tables,
-                lengths, nn)
+                lengths, nn, scale=scale)
     out = dn(o.astype(x.dtype).reshape(B, S, H * hd), params["wo"], la("wo"))
     new_cache = {"k_pool": kp, "v_pool": vp}
     if quant:
@@ -532,10 +534,23 @@ def kv_cache_specs() -> Params:
             "pos": P()}
 
 
+def kv_pack(n_kv_heads: int, head_dim: int) -> int:
+    """KV heads stored side by side in one row of a bf16 pool.  The TPU
+    lays an array's last axis out in tiles of 128 lanes, so a head of 64
+    would sit in a row half padding, and every kernel call would copy the
+    pool to pad it.  Where the heads divide into whole 128-lane rows (Kv 8
+    of 64 -> 4 rows of 2), the pool is ``(NB, bs, Kv // f, f * hd)``: a
+    reshape of the logical ``(NB, bs, Kv, hd)``, with no padding, that the
+    kernels read in place.  1 (no packing) otherwise."""
+    f = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    return f if n_kv_heads % f == 0 else 1
+
+
 def init_paged_kv_cache(cfg, num_blocks: int, block_size: int, dtype,
                         kv_dtype: str = "f32") -> Params:
     """One K/V pool per layer, shared by every serving slot: blocks are
     handed to slots by the host-side block table (serving/kv_cache.py).
+    Unquantized pools pack narrow heads into whole rows (:func:`kv_pack`).
 
     ``kv_dtype="int8"`` stores the pools as int8 with one fp32 scale per
     (block, position, kv-head) riding as ``k_scale``/``v_scale`` leaves —
@@ -554,8 +569,10 @@ def init_paged_kv_cache(cfg, num_blocks: int, block_size: int, dtype,
                                      dtype=jnp.float32)}
     if kv_dtype != "f32":
         raise ValueError(f"kv_dtype must be 'f32' or 'int8', got {kv_dtype!r}")
-    return {"k_pool": jnp.zeros((num_blocks, block_size, Kv, hd), dtype=dtype),
-            "v_pool": jnp.zeros((num_blocks, block_size, Kv, hd), dtype=dtype)}
+    f = kv_pack(Kv, hd)
+    shape = (num_blocks, block_size, Kv // f, f * hd)
+    return {"k_pool": jnp.zeros(shape, dtype=dtype),
+            "v_pool": jnp.zeros(shape, dtype=dtype)}
 
 
 def paged_kv_cache_specs(kv_dtype: str = "f32") -> Params:

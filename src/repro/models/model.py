@@ -120,13 +120,15 @@ def param_specs(cfg) -> Params:
 
 def _apply_block(entry: str, bp: Params, x, cfg, positions,
                  adapters=None, lora_scale=1.0, cache=None,
-                 adapter_ids=None, paged=None, n_new=None):
+                 adapter_ids=None, paged=None, n_new=None, period=None):
     """One layer. Returns (x, new_cache, aux).  ``n_new``: (B,) int32 valid
-    leading tokens per row in a ragged prefill chunk (see prefill_step)."""
+    leading tokens per row in a ragged prefill chunk (see prefill_step).
+    ``period``: the layer's period, where ``cache`` is a Mamba-2 state
+    stacked over all periods (see :func:`_cached_scan`)."""
     mixer, mlp = _parse(entry)
     ad = adapters or {}
     aux = jnp.zeros((), jnp.float32)
-    h = L.apply_norm(bp["norm1"], x, cfg.norm_type)
+    h = L.apply_norm(bp["norm1"], x, cfg.norm_type, cfg.norm_eps)
     if mixer == "attn":
         out, new_mix_cache = L.multihead_attention(
             bp["mixer"], h, cfg, positions, ad.get("mixer"), lora_scale,
@@ -134,18 +136,47 @@ def _apply_block(entry: str, bp: Params, x, cfg, positions,
     else:
         out, new_mix_cache = mamba2.apply_mamba(
             bp["mixer"], h, cfg, ad.get("mixer"), lora_scale, ssm_cache=cache,
-            adapter_ids=adapter_ids, n_new=n_new)
-    x = x + out
+            adapter_ids=adapter_ids, n_new=n_new, period=period)
+    x = _residual(x, out, cfg)
     if mlp != "none":
-        h = L.apply_norm(bp["norm2"], x, cfg.norm_type)
+        h = L.apply_norm(bp["norm2"], x, cfg.norm_type, cfg.norm_eps)
         if mlp == "mlp":
             out = L.apply_mlp(bp["mlp"], h, cfg.mlp_type, ad.get("mlp"),
                               lora_scale, adapter_ids=adapter_ids)
         else:
             out, aux = moe_lib.apply_moe(bp["mlp"], h, cfg, ad.get("mlp"),
                                          lora_scale, adapter_ids=adapter_ids)
-        x = x + out
+        x = _residual(x, out, cfg)
     return x, new_mix_cache, aux
+
+
+def _residual(x, out, cfg):
+    """The residual add, with the sublayer's output scaled by
+    ``cfg.residual_multiplier`` (in the activation dtype, as published)."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.residual_multiplier, out.dtype)
+    return x + out
+
+
+def _embed(params: Params, tokens, cfg):
+    """Token embeddings times ``cfg.resolved_embedding_multiplier``."""
+    dtype = L.dt(cfg.dtype)
+    x = params["embed"].astype(dtype)[tokens]
+    mult = cfg.resolved_embedding_multiplier
+    if mult != 1.0:
+        x = x * jnp.asarray(mult, dtype)
+    return x
+
+
+def _unembed(params: Params, x, cfg):
+    """Final norm and the (tied or separate) head: float32 logits divided
+    by ``cfg.logits_scaling``."""
+    x = L.apply_norm(params["final_norm"], x, cfg.norm_type, cfg.norm_eps)
+    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
+    logits = L.matmul(x, head.astype(L.dt(cfg.dtype)), out_dtype=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def forward(params: Params, tokens: jnp.ndarray, cfg,
@@ -161,9 +192,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg,
 
     Returns (logits (B, S, V), aux_loss scalar)."""
     dtype = L.dt(cfg.dtype)
-    x = params["embed"].astype(dtype)[tokens]
-    if cfg.family == "dense" and cfg.tie_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dtype)  # gemma-style scaling
+    x = _embed(params, tokens, cfg)
     if extra_embeds is not None:
         x = jnp.concatenate([extra_embeds.astype(dtype), x], axis=1)
     B, S, _ = x.shape
@@ -200,10 +229,7 @@ def forward(params: Params, tokens: jnp.ndarray, cfg,
 
     if last_only:  # serving prefill: unembed only the final position
         x = x[:, -1:]
-    x = L.apply_norm(params["final_norm"], x, cfg.norm_type)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = L.matmul(x, head.astype(dtype), out_dtype=jnp.float32)
-    return logits, aux
+    return _unembed(params, x, cfg), aux
 
 
 # ---------------------------------------------------------------------------
@@ -343,11 +369,13 @@ def _cached_scan(params: Params, cache: Params, tokens: jnp.ndarray,
                  positions: jnp.ndarray, cfg, adapters, lora_scale,
                  adapter_ids, paged, n_new) -> Tuple[jnp.ndarray, Params]:
     """Shared cache-threading scaffold of decode_step / prefill_step:
-    embed, period scan with per-block caches, final norm, unembed."""
-    dtype = L.dt(cfg.dtype)
-    x = params["embed"].astype(dtype)[tokens]
-    if cfg.family == "dense" and cfg.tie_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, dtype)
+    embed, period scan with per-block caches, final norm, unembed.
+
+    K/V pools ride the scan as inputs and outputs, one period's slice
+    each.  A Mamba-2 block's state stack (every period's) rides as a
+    carry instead: its layer reads and writes its own period in place,
+    inside the ``ssm`` scope, so each step moves the state once."""
+    x = _embed(params, tokens, cfg)
     # serving: batch rows (slots) are data-parallel over the mesh; the
     # sharded engine keeps slots shard-contiguous, so partitioning the
     # fused batch axis here lands each KV shard's rows on its devices.
@@ -357,27 +385,37 @@ def _cached_scan(params: Params, cache: Params, tokens: jnp.ndarray,
     block_names = _block_names(cfg)
     ad_blocks = (adapters or {}).get("blocks", {})
 
-    def period_body(x, xs):
-        new_caches = {}
+    recurrent = [name for name, entry in zip(block_names, cfg.layer_pattern)
+                 if _parse(entry)[0] == "mamba"]
+
+    def period_body(carry, xs):
+        x, states = carry
+        states, new_caches = dict(states), {}
         for name in block_names:
             entry = cfg.layer_pattern[block_names.index(name)]
-            x, nc, _ = _apply_block(entry, xs[name], x, cfg, positions,
-                                    xs.get("__ad_" + name), lora_scale,
-                                    cache=xs["__cache_" + name],
-                                    adapter_ids=adapter_ids, paged=paged,
-                                    n_new=n_new)
-            new_caches[name] = nc
-        return x, new_caches
+            stacked = name in states
+            x, nc, _ = _apply_block(
+                entry, xs[name], x, cfg, positions, xs.get("__ad_" + name),
+                lora_scale,
+                cache=states[name] if stacked else xs["__cache_" + name],
+                adapter_ids=adapter_ids, paged=paged, n_new=n_new,
+                period=xs["__period"] if stacked else None)
+            (states if stacked else new_caches)[name] = nc
+        return (x, states), new_caches
 
     xs = dict(params["blocks"])
+    if recurrent:
+        xs["__period"] = jnp.arange(cfg.n_periods)
     for name in block_names:
-        xs["__cache_" + name] = cache["blocks"][name]
+        if name not in recurrent:
+            xs["__cache_" + name] = cache["blocks"][name]
         if name in ad_blocks:
             xs["__ad_" + name] = ad_blocks[name]
-    x, new_caches = jax.lax.scan(period_body, x, xs,
-                             unroll=min(cfg.scan_unroll, cfg.n_periods))
+    states = {name: cache["blocks"][name] for name in recurrent}
+    (x, states), new_caches = jax.lax.scan(
+        period_body, (x, states), xs,
+        unroll=min(cfg.scan_unroll, cfg.n_periods))
+    new_caches.update(states)
 
-    x = L.apply_norm(params["final_norm"], x, cfg.norm_type)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = L.matmul(x, head.astype(dtype), out_dtype=jnp.float32)
+    logits = _unembed(params, x, cfg)
     return L.maybe_shard(logits, P("data", None, None)), {"blocks": new_caches}

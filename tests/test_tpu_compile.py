@@ -1,7 +1,8 @@
 """Compile-only guards for one TPU v5e chip.
 
-The kernels of the serving path, and the whole ``gemma-2b`` paged decode
-step on the Pallas backend, are compiled here for a v5e chip that is
+The kernels of the serving path, and the whole paged decode steps of
+``gemma-2b`` and ``granite-4.0-h-micro`` on the Pallas backend, are
+compiled here for a v5e chip that is
 described, not attached (``jax.experimental.topologies``): the chip's
 compiler refuses misaligned tiles, scoped-VMEM overruns and programs that
 do not fit its memory, none of which interpret mode sees.  Nothing runs.
@@ -111,16 +112,26 @@ def test_paged_decode_kernel_reads_the_pool_in_place(one_chip, cell, quant):
         _spec((B, 1, H, 128), jnp.bfloat16, one_chip), kp, kp,
         _spec((B, MB), jnp.int32, one_chip), _spec((B,), jnp.int32, one_chip),
         ks, ks)
+    readers = _readers(compiled, pool)
+    assert readers and all(r.startswith("paged_attention.")
+                           for r in readers), readers
+
+
+def _shapes_and_lines(compiled):
     lines = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*)$",
                        compiled.as_text(), re.M)
     dims = {name: tuple(int(d) for d in m.group(1).split(","))
             for name, rest in lines
             if (m := re.match(r"\(?\w+\[([\d,]+)\]", rest))}
-    readers = {name for name, rest in lines
-               if any(dims.get(o) == pool
-                      for o in re.findall(r"%([\w.\-]+)", rest))}
-    assert readers and all(r.startswith("paged_attention.")
-                           for r in readers), readers
+    return dims, lines
+
+
+def _readers(compiled, shape):
+    """The instructions that take an array of ``shape`` as an operand."""
+    dims, lines = _shapes_and_lines(compiled)
+    return {name for name, rest in lines
+            if any(dims.get(o) == shape
+                   for o in re.findall(r"%([\w.\-]+)", rest))}
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -218,3 +229,74 @@ def test_gemma_2b_decode_step_compiles_for_v5e(one_chip):
         params, cache, _spec((SLOTS, 1), jnp.int32, one_chip), ln, bank,
         _spec((SLOTS,), jnp.int32, one_chip), bt)
     assert compiled.memory_analysis().argument_size_in_bytes < V5E_HBM
+
+
+# the granite-4.0-h-micro cell: 16 slots, 32 query and 8 KV heads of 64,
+# a 4096-token context in blocks of 16, prefill chunks of 128; its bf16
+# pools pack two heads to a 128-lane row (``layers.kv_pack``)
+GRANITE = dict(B=16, H=32, Kv=8, hd=64, NB=4097, MB=256, T=128)
+
+
+def test_granite_pools_are_read_in_place(one_chip):
+    """At the granite cell's shapes the decode kernel alone takes the
+    packed pool as an operand, and neither wrapper pads it to 128 lanes
+    (which, at head size 64, would copy the pool on every call).  The
+    prefill kernel's head-major transpose of the pool remains (ROADMAP
+    queue 1)."""
+    g = GRANITE
+    from repro.models.layers import kv_pack
+    f = kv_pack(g["Kv"], g["hd"])
+    assert f == 2
+    pool = (g["NB"], BLOCK, g["Kv"] // f, f * g["hd"])
+    padded = (g["NB"], BLOCK, g["Kv"], 128)
+    kp = _spec(pool, jnp.bfloat16, one_chip)
+    bt = _spec((g["B"], g["MB"]), jnp.int32, one_chip)
+    ln = _spec((g["B"],), jnp.int32, one_chip)
+    dec = _compile(lambda q, kp, vp, bt, ln: ops.paged_gqa_attention(
+        q, kp, vp, bt, ln, scale=1 / 64),
+        _spec((g["B"], 1, g["H"], g["hd"]), jnp.bfloat16, one_chip),
+        kp, kp, bt, ln)
+    readers = _readers(dec, pool)
+    assert readers and all(r.startswith("paged_attention.")
+                           for r in readers), readers
+    kn = _spec((g["B"], g["T"], g["Kv"], g["hd"]), jnp.bfloat16, one_chip)
+    pre = _compile(
+        lambda q, k, v, kp, vp, bt, ln, nn: ops.paged_prefill_gqa_attention(
+            q, k, v, kp, vp, bt, ln, nn, scale=1 / 64)[0],
+        _spec((g["B"], g["T"], g["H"], g["hd"]), jnp.bfloat16, one_chip),
+        kn, kn, kp, kp, bt, ln, ln)
+    for compiled in (dec, pre):
+        dims, _ = _shapes_and_lines(compiled)
+        assert padded not in dims.values()
+
+
+def test_granite_decode_step_compiles_for_v5e(one_chip):
+    """The whole paged decode step of granite-4.0-h-micro at published
+    widths: 36 Mamba-2 layers' float32 state for 16 slots, the 4 NoPE GQA
+    layers' packed pools, bf16 weights and a 16-client adapter bank,
+    Pallas backend."""
+    from repro.configs.granite_4p0_h_micro import CONFIG as GCFG
+
+    g = GRANITE
+    model = get_model(GCFG)
+    place = lambda tree: jax.tree.map(  # noqa: E731
+        lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    bank = jax.tree.map(
+        lambda s: _spec((s.shape[0], g["B"]) + s.shape[1:], s.dtype,
+                        one_chip),
+        jax.eval_shape(lambda k: init_adapters(k, GCFG),
+                       jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(lambda: model.init_paged_decode_cache(
+        g["B"], g["NB"], BLOCK)))
+    assert cache["blocks"]["b0"]["h"].shape == (4, g["B"], 64, 64, 128)
+    ln = _spec((g["B"],), jnp.int32, one_chip)
+    compiled = _compile(
+        lambda p, c, tok, pos, bank, ids, bt: model.decode_step(
+            p, c, tok, pos, adapters=bank, lora_scale=2.0, adapter_ids=ids,
+            block_tables=bt, paged_backend="pallas"),
+        params, cache, _spec((g["B"], 1), jnp.int32, one_chip), ln, bank,
+        ln, _spec((g["B"], g["MB"]), jnp.int32, one_chip))
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < V5E_HBM
